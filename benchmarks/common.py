@@ -150,11 +150,22 @@ def emit(name: str, us_per_call: float, derived: str) -> None:
     print(f"{name},{us_per_call:.1f},{derived}")
 
 
+def device_record() -> dict:
+    """The device a result was measured on.  Off a TPU the run is a CPU
+    rehearsal: its times and ratios are never speeds."""
+    import jax
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices),
+            "rehearsal": devices[0].platform != "tpu"}
+
+
 def save_json(name: str, payload) -> str:
     os.makedirs(RESULTS_DIR, exist_ok=True)
     path = os.path.join(RESULTS_DIR, f"{name}.json")
     with open(path, "w") as f:
-        json.dump(payload, f, indent=1)
+        json.dump({"device": device_record(), **payload}, f, indent=1)
     if name.startswith("BENCH"):
         # canonical copy at the repo root: the perf-trajectory tracker scans
         # there, not under results/bench/

@@ -350,16 +350,15 @@ def main(argv=None) -> dict:
                     default="numpy", help="which engine lane to run")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persist jit-compiled launches under DIR (JAX "
-                         "compilation cache); cold runs seed it, warm runs "
-                         "load from it")
+                    help="persist jit-compiled launches under DIR unless "
+                         "JAX_COMPILATION_CACHE_DIR is set (default: the "
+                         "checkout's .jax_cache); cold runs seed it, warm "
+                         "runs load from it")
     args = ap.parse_args(argv)
 
-    compile_cache_on = False
-    if args.compile_cache:
-        from repro.serve import enable_compilation_cache
+    from repro.serve import enable_compilation_cache
 
-        compile_cache_on = enable_compilation_cache(args.compile_cache)
+    compile_cache = enable_compilation_cache(args.compile_cache)
 
     if args.smoke:
         n_tasks, n_data, iters, eq_evals, eq_unimproved = 40, 100, 8, 2000, 10
@@ -369,7 +368,7 @@ def main(argv=None) -> dict:
     payload = {"scale": {"n_tasks": n_tasks, "n_data": n_data,
                          "smoke": args.smoke},
                "backend": args.backend,
-               "compile_cache": compile_cache_on}
+               "compile_cache": compile_cache}
 
     if args.backend == "suite":
         payload["suite_lane"] = suite_lane(args)
@@ -409,7 +408,7 @@ def main(argv=None) -> dict:
             # cold-start accounting: with --compile-cache a second CI run
             # should show this dropping toward zero (persistent cache hit)
             "compile_seconds": lane["device"]["compile_seconds"],
-            "compile_cache": compile_cache_on,
+            "compile_cache": compile_cache,
             "certified": lane["certified"],
             **budget_rec,
         }, scale=payload["scale"])

@@ -272,7 +272,9 @@ def main(argv=None) -> dict:
                     default="both")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persist jit-compiled launches under DIR")
+                    help="persist jit-compiled launches under DIR unless "
+                         "JAX_COMPILATION_CACHE_DIR is set (default: the "
+                         "checkout's .jax_cache)")
     args = ap.parse_args(argv)
 
     prof = profile(args.smoke)

@@ -27,13 +27,16 @@ Static-shape discipline:
 
 Parity contract (asserted by ``tests/test_device_search.py`` and the
 ``search_bench`` device lane): with ``W=1``, float64 (the engine always
-traces under ``jax.experimental.enable_x64``), and ``mem_update_period``
+traces under ``jax.enable_x64``), and ``mem_update_period``
 large enough that Algorithm 3 never fires inside the horizon, the engine's
 trajectory — history, incumbent, iteration and eval counts — is
 **bit-for-bit identical** to the legacy ``tabu_search`` / ``tabu_multiwalk``
 drivers on the numpy backend, as long as the trajectory never enters the
 perturbation branch.  This holds because every float op replays the numpy
-engine's operand set and order: max reductions are order-independent,
+engine's operand set and order: the engine multiplies no floats (block
+size × access time comes precomputed in ``InstancePack.io_cost``, so XLA
+cannot contract a multiply and an add into one fused multiply-add), max
+reductions are order-independent,
 durations replay the global cumsum-difference via a blocked *sequential*
 scan (``jnp.cumsum`` does NOT match ``np.cumsum`` bitwise — measured, not
 assumed), approximate-window sums replay the scalar left-to-right order,
@@ -259,14 +262,13 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
     out_valid = ia["out_valid"]
     out_ptr = ia["out_ptr"]
     proc_time = ia["proc_time"]
-    access_time = ia["access_time"]
-    data_size = ia["data_size"]
+    io_cost = ia["io_cost"]          # data_size x access_time, host-formed
     compat = ia["compat"]
     n = ia["n"]                      # real sizes: scalars, traced in batch
     p = ia["p"]
     n_b, p_b = proc_time.shape
     s_b = n_b + 1
-    d_b = data_size.shape[0]
+    d_b = io_cost.shape[0]
     W, C, K = w_count, crit_cap, params.top_k
     NPOS = params.n_change_core_positions
     M_n7 = 2 * C
@@ -294,9 +296,9 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
         """``solution.durations`` replayed bit-exactly per row: global
         sequential cumsum over the CSR edge values, then indptr differences."""
         def io_time(idx, owner, valid, ptr):
-            rate = access_time[assign_rows[:, owner], mem_rows[:, idx]]
-            vals = jnp.where(valid[None, :],
-                             data_size[idx][None, :] * rate, 0.0)
+            cost = io_cost[idx[None, :], assign_rows[:, owner],
+                           mem_rows[:, idx]]
+            vals = jnp.where(valid[None, :], cost, 0.0)
             c = _seq_cumsum(vals)
             return c[:, ptr[1:]] - c[:, ptr[:-1]]
 
@@ -364,8 +366,7 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
         ok = blocks >= 0
         bsafe = jnp.where(ok, blocks, 0)
         memv = mem_w[wi[:, None, None], bsafe]               # (W, M, L)
-        vals = jnp.where(ok, data_size[bsafe]
-                         * access_time[b[..., None], memv], 0.0)
+        vals = jnp.where(ok, io_cost[bsafe, b[..., None], memv], 0.0)
         tot = jnp.zeros(vals.shape[:2], f64)
         for jj in range(vals.shape[2]):
             tot = tot + vals[:, :, jj]
@@ -969,7 +970,7 @@ def device_multiwalk(
     restarting).  Both are None-default and cost nothing when unused
     (DESIGN.md §13).
     """
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     params = params or TSParams()
     cfg = config or DeviceConfig()
@@ -1274,7 +1275,7 @@ def solve_instances(
     fans out to streaming clients.
     """
     import jax
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     params = params or TSParams()
     cfg = config or DeviceConfig()
@@ -1520,15 +1521,16 @@ def warm_launches(
     shape — shared buckets, dense widths, padded edge lengths; ``walks`` and
     ``params`` supply the compile-relevant search knobs; ``batch_sizes`` are
     the vmap widths to warm (the serve engine's quantized batch sizes).
-    Each missing program is compiled by invoking it once for one
-    ``sync_every`` horizon on a replicated copy of the first instance, so
-    the warm-up work is bounded and the executable lands in both the
-    in-process launch LRU and — when ``jax_compilation_cache_dir`` is set —
-    JAX's persistent compilation cache.  Returns per-size compile seconds
-    and launch-cache counter deltas.
+    Each missing program is compiled by invoking it once on a replicated
+    copy of the first instance with every walk inactive, so the round loop
+    exits at once and the timed call is the compile, not a search horizon;
+    the executable lands in both the in-process launch LRU and — when
+    ``jax_compilation_cache_dir`` is set — JAX's persistent compilation
+    cache.  Returns per-size compile seconds and launch-cache counter
+    deltas.
     """
     import jax
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from .api import multiwalk_inits  # lazy: api imports this module lazily
 
@@ -1552,6 +1554,7 @@ def warm_launches(
 
         ia = ia_from_pack(ip)
         state = pack_state(ip, sols, scheds, params.seed)
+        state["active"][:] = False  # compile only: zero rounds run
         for bs in sorted({int(b) for b in batch_sizes}):
             if bs < 1:
                 raise ValueError("batch sizes must be positive")

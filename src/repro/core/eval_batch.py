@@ -24,7 +24,7 @@ per-bucket event cumsum).  The optional JAX path (``backend="jax"``) runs
 the forward/backward sweeps through ``repro.kernels.schedule_dp`` — the
 gather-side dense level loop (XLA) or the fused Pallas kernel on TPU — on
 padded shape buckets; it matches to float32 tolerance (bit-exact under
-``jax_enable_x64``) and falls back to NumPy when JAX is unavailable.
+``jax_enable_x64``) and raises ``ImportError`` when JAX is unavailable.
 Compiled sweeps are cached per shape bucket in a bounded LRU
 (``BatchEvaluator.cache_info()`` reports hits/misses/size for the
 benchmarks).
@@ -37,7 +37,6 @@ oracle for parity tests and benchmarks.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Sequence
 
 import numpy as np
@@ -384,13 +383,8 @@ class BatchEvaluator:
         if backend not in BACKENDS:
             raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
         if backend == "jax" and not _jax_available():
-            warnings.warn(
-                "backend='jax' requested but jax is not importable; "
-                "falling back to the NumPy batch path",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            backend = "numpy"
+            raise ImportError("backend='jax' needs jax, which is not "
+                              "importable here")
         self.inst = inst
         self.backend = backend
         self.jax_impl = jax_impl  # None = auto (pallas on TPU, xla elsewhere)
@@ -816,7 +810,7 @@ def _jax_available() -> bool:
         import jax  # noqa: F401
 
         return True
-    except Exception:
+    except ImportError:
         return False
 
 
@@ -870,7 +864,7 @@ def _jax_sweeps(engine: BatchEvaluator, packed: PackedSolutions, dur: np.ndarray
         else:
             adj = np.asarray(graph.adj)
             fn = lambda d, mp, ms: sdp.sweep_pallas(  # noqa: E731
-                adj, d, mp, n, tails=tails,
+                adj, d, mp, ms, n, tails=tails,
                 interpret=impl == "pallas_interpret")
         engine._jax_fns.put(key, fn)
     start, finish, level, n_done, q = fn(

@@ -76,8 +76,11 @@ class InstancePack:
     out_valid: np.ndarray
     out_ptr: np.ndarray
     proc_time: np.ndarray   # (n_b, p_b) f64; pad tasks 0.0, pad procs +inf
-    access_time: np.ndarray  # (p_b, n_mems) f64 (pad procs repeat row 0)
-    data_size: np.ndarray   # (d_b,) f64 (pads 0)
+    # io_cost[d, c, m] = data_size[d] * access_time[c, m], formed here so the
+    # device never multiplies: a compiled multiply feeding an add may become
+    # one fused multiply-add, which no longer rounds like numpy
+    io_cost: np.ndarray     # (d_b, p_b, n_mems) f64; pad blocks 0.0, pad
+                            # procs repeat proc 0
     compat: np.ndarray      # (n_b, p_b) bool
 
     @property
@@ -140,6 +143,7 @@ def pack_instance(inst: Instance, *, n_b: int | None = None,
     at[p:] = inst.access_time[0]
     ds = np.zeros(d_b)
     ds[:d] = inst.data_size
+    io_cost = ds[:, None, None] * at[None, :, :]
     compat = np.zeros((n_b, p_b), dtype=bool)
     compat[:n, :p] = np.isfinite(inst.proc_time)
     return InstancePack(
@@ -150,8 +154,7 @@ def pack_instance(inst: Instance, *, n_b: int | None = None,
         out_blk=_dense_blocks(n, n_b, inst.out_indptr, inst.out_idx, widths[3]),
         in_idx=in_idx, in_owner=in_owner, in_valid=in_valid, in_ptr=in_ptr,
         out_idx=out_idx, out_owner=out_owner, out_valid=out_valid,
-        out_ptr=out_ptr, proc_time=pt, access_time=at, data_size=ds,
-        compat=compat,
+        out_ptr=out_ptr, proc_time=pt, io_cost=io_cost, compat=compat,
     )
 
 

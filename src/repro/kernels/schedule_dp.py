@@ -20,11 +20,11 @@ bincount — and the whole level loop lives in one compiled ``while_loop``:
   ``backend="jax"`` path on CPU/GPU.
 * :func:`sweep_pallas` — the Pallas TPU kernel (``interpret=True`` runs the
   same kernel through the interpreter on CPU, used by the parity tests and
-  the CI smoke leg).  It replaces the per-slot gather with a masked
-  (rows, n, n) reduce over the combined predecessor mask so the inner loop
-  maps onto the VPU without dynamic vector gathers; the backward sweep
-  reuses the *transposed* mask (machine-succ is the transpose of
-  machine-pred), so one mask build serves both directions.
+  the CI smoke leg).  It replaces the per-slot gather with masked reduces
+  so the inner loop maps onto the VPU without dynamic vector gathers: one
+  candidate row per grid step, predecessors reduced 128 lanes at a time
+  over the DAG adjacency OR the machine-pred one-hot; the backward sweep
+  does the same over the transposed adjacency and the machine successors.
 
 Both implementations are **bit-exact** with the NumPy engine when run in
 float64 (every reduction is a pure float max over the identical operand set,
@@ -234,123 +234,145 @@ def sweep_xla(pred_mat, succ_mat, dur, mpred, msucc, n_valid: int,
 # --------------------------------------------------------------------------- #
 # Pallas kernel                                                                #
 # --------------------------------------------------------------------------- #
+_LANES = 128  # TPU vector lane width: the kernel's task axis is a multiple
+
+
 @functools.lru_cache(maxsize=16)
-def _build_pallas_sweep(n_b: int, n_valid: int, block_rows: int,
-                        tails: bool, interpret: bool, dtype_name: str):
+def _build_pallas_sweep(n_b: int, n_valid: int, tails: bool, interpret: bool,
+                        dtype_name: str):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    assert n_b % _LANES == 0
     fdt = jnp.dtype(dtype_name)
     neg_inf = float(-np.inf)
 
-    def kernel(adj_ref, mpred_ref, dur_ref, start_ref, finish_ref,
-               level_ref, ndone_ref, q_ref):
-        adj = adj_ref[:] != 0                              # (n_b, n_b)
-        mpred = mpred_ref[:]                               # (Bb, n_b)
-        dur = dur_ref[:]
-        col = jax.lax.broadcasted_iota(jnp.int32, (n_b,), 0)
-        valid = (col < n_valid)[None, :]
-        # combined predecessor mask: P[b, i, j] == (j precedes i)
-        pmask = adj[None, :, :] | (mpred[:, :, None] == col[None, None, :])
+    def kernel(adj_ref, adjt_ref, mpred_ref, msucc_ref, dur_ref,
+               start_ref, finish_ref, level_ref, ndone_ref, q_ref):
+        # one candidate row per grid step; Mosaic cannot carry i1 vectors
+        # through a loop, so every carried flag is an int32 0/1 array
+        dur = dur_ref[:]                                   # (1, n_b)
+        valid = (jax.lax.broadcasted_iota(jnp.int32, (1, n_b), 1)
+                 < n_valid).astype(jnp.int32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _LANES), 2)
 
-        def run(mask, node_add, active_rows):
+        def link_max(graph_ref, mlink, vals, fill):
+            """``max`` of ``vals[j]`` over the j that link into each i: DAG
+            edges ``graph[i, j]`` plus the machine link ``mlink[i]``.  The
+            j axis is reduced one 128-lane chunk at a time, so the masked
+            temporaries are (n_b, 128) whatever n_b is."""
+            out = None
+            for c in range(0, n_b, _LANES):
+                g = graph_ref[:, pl.ds(c, _LANES)]
+                linked = (g[None, :, :] != 0) \
+                    | (mlink[:, :, None] == lane + c)
+                v = jax.lax.slice_in_dim(vals, c, c + _LANES, axis=1)
+                m = jnp.where(linked, v[:, None, :], fill).max(axis=2)
+                out = m if out is None else jnp.maximum(out, m)
+            return out
+
+        def run(graph_ref, mlink, node_add, active):
+            def ready_of(done):
+                waiting = link_max(graph_ref, mlink, 1 - done, 0)
+                return valid * active * (1 - done) * (1 - waiting)
+
             def cond(state):
                 _, _, _, ready, lev = state
-                return jnp.logical_and(ready.any(), lev <= n_valid)
+                return jnp.logical_and(ready.max() > 0, lev <= n_valid)
 
             def body(state):
                 val, level, done, ready, lev = state
-                contrib = jnp.where(mask, val[:, None, :], neg_inf)
-                base = jnp.maximum(contrib.max(axis=2), 0.0).astype(fdt)
-                v = base + node_add
-                val = jnp.where(ready, v, val)
-                level = jnp.where(ready, lev, level)
-                done = done | ready
-                stalled = (mask & ~done[:, None, :]).any(axis=2)
-                ready = valid & active_rows & ~done & ~stalled
-                return val, level, done, ready, lev + 1
+                base = jnp.maximum(link_max(graph_ref, mlink, val, neg_inf),
+                                   0.0).astype(fdt)
+                go = ready != 0
+                val = jnp.where(go, base + node_add, val)
+                level = jnp.where(go, lev, level)
+                done = jnp.maximum(done, ready)
+                return val, level, done, ready_of(done), lev + 1
 
-            bb = node_add.shape[0]
-            val = jnp.zeros((bb, n_b), fdt)
-            level = jnp.zeros((bb, n_b), jnp.int32)
-            done = jnp.zeros((bb, n_b), bool)
-            stalled = (mask & ~done[:, None, :]).any(axis=2)
-            ready = valid & active_rows & ~done & ~stalled
+            val = jnp.zeros((1, n_b), fdt)
+            level = jnp.zeros((1, n_b), jnp.int32)
+            done = jnp.zeros((1, n_b), jnp.int32)
             val, level, done, _, _ = jax.lax.while_loop(
-                cond, body, (val, level, done, ready, jnp.int32(0)))
-            return val, level, done
+                cond, body, (val, level, done, ready_of(done), jnp.int32(0)))
+            return val, level, done != 0
 
-        finish, level, done = run(pmask, dur, jnp.ones_like(mpred[:, :1], bool))
-        contrib = jnp.where(pmask, finish[:, None, :], neg_inf)
-        start = jnp.where(done, jnp.maximum(contrib.max(axis=2), 0.0).astype(fdt), 0.0)
+        mpred = mpred_ref[:]
+        finish, level, done = run(adj_ref, mpred, dur, 1)
+        head = link_max(adj_ref, mpred, finish, neg_inf)
+        start = jnp.where(done, jnp.maximum(head, 0.0).astype(fdt), 0.0)
         finish = jnp.where(done, finish, 0.0)
-        n_done = (done & valid).sum(axis=1).astype(jnp.int32)
+        n_done = jnp.where(done, valid, 0).sum(axis=1, keepdims=True)
         start_ref[:] = start
         finish_ref[:] = finish
         level_ref[:] = level
         ndone_ref[:] = n_done
         if tails:
-            # successor mask is the transposed predecessor mask (machine-succ
-            # is the transpose of machine-pred), so one mask serves both;
+            # successors: the transposed DAG plus the machine successor;
             # operands mirror the scalar heads_tails (dur = finish - start)
-            smask = jnp.swapaxes(pmask, 1, 2)
-            feasible = (n_done == n_valid)[:, None]
-            q, _, qdone = run(smask, finish - start, feasible)
+            feasible = (n_done == n_valid).astype(jnp.int32)
+            q, _, qdone = run(adjt_ref, msucc_ref[:], finish - start,
+                              feasible)
             q_ref[:] = jnp.where(qdone, q, 0.0)
         else:
             q_ref[:] = jnp.zeros_like(dur)
 
     @jax.jit
-    def call(adj_u8, mpred, dur):
+    def call(adj, adjt, mpred, msucc, dur):
+        # rows ride as (b, 1, n_b): a (1, n_b) block spans the array's last
+        # two dims, which is a tile Mosaic accepts for any row count
         b = dur.shape[0]
-        grid = (b // block_rows,)
-        row_spec = pl.BlockSpec((block_rows, n_b), lambda i: (i, 0))
-        outs = pl.pallas_call(
+        row_spec = pl.BlockSpec((None, 1, n_b), lambda i: (i, 0, 0))
+        graph_spec = pl.BlockSpec((n_b, n_b), lambda i: (0, 0))
+        start, finish, level, n_done, q = pl.pallas_call(
             kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((n_b, n_b), lambda i: (0, 0)),
-                row_spec,
-                row_spec,
-            ],
+            grid=(b,),
+            in_specs=[graph_spec, graph_spec, row_spec, row_spec, row_spec],
             out_specs=[row_spec, row_spec, row_spec,
-                       pl.BlockSpec((block_rows,), lambda i: (i,)),
+                       pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0)),
                        row_spec],
             out_shape=[
-                jax.ShapeDtypeStruct((b, n_b), fdt),
-                jax.ShapeDtypeStruct((b, n_b), fdt),
-                jax.ShapeDtypeStruct((b, n_b), jnp.int32),
-                jax.ShapeDtypeStruct((b,), jnp.int32),
-                jax.ShapeDtypeStruct((b, n_b), fdt),
+                jax.ShapeDtypeStruct((b, 1, n_b), fdt),
+                jax.ShapeDtypeStruct((b, 1, n_b), fdt),
+                jax.ShapeDtypeStruct((b, 1, n_b), jnp.int32),
+                jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
+                jax.ShapeDtypeStruct((b, 1, n_b), fdt),
             ],
             interpret=interpret,
-        )(adj_u8, mpred, dur)
-        return outs
+        )(adj, adjt, mpred[:, None, :], msucc[:, None, :], dur[:, None, :])
+        return (start[:, 0], finish[:, 0], level[:, 0], n_done[:, 0, 0],
+                q[:, 0])
 
     return call
 
 
-def sweep_pallas(adj, dur, mpred, n_valid: int, *, tails: bool = True,
-                 block_rows: int = 8, interpret: bool = False):
-    """Pallas sweep over ``(B, n_b)`` rows (B padded to ``block_rows``).
+def sweep_pallas(adj, dur, mpred, msucc, n_valid: int, *, tails: bool = True,
+                 interpret: bool = False):
+    """Pallas sweep over ``(B, n_b)`` rows, one row per grid step.
 
-    ``msucc`` is not needed: the backward mask is the transpose of the
-    forward one.  Returns ``(start, finish, level, n_done, q)``.
+    Tasks are padded to a multiple of 128 lanes (pad tasks are never valid,
+    so they stay at zero).  Returns ``(start, finish, level, n_done, q)``.
     """
     import jax.numpy as jnp
 
     b, n_b = dur.shape
-    bp = block_rows * ((b + block_rows - 1) // block_rows)
-    if bp != b:
-        dur = jnp.concatenate([dur, jnp.zeros((bp - b, n_b), dur.dtype)])
-        mpred = jnp.concatenate(
-            [mpred, jnp.full((bp - b, n_b), -1, mpred.dtype)])
-    call = _build_pallas_sweep(n_b, int(n_valid), block_rows, bool(tails),
+    n_p = _LANES * ((n_b + _LANES - 1) // _LANES)
+
+    def pad(a, fill, dtype):
+        return jnp.pad(jnp.asarray(a, dtype), ((0, 0), (0, n_p - n_b)),
+                       constant_values=fill)
+
+    graph = np.zeros((n_p, n_p), np.int32)
+    graph[:n_b, :n_b] = adj
+    call = _build_pallas_sweep(n_p, int(n_valid), bool(tails),
                                bool(interpret), jnp.dtype(dur.dtype).name)
     start, finish, level, n_done, q = call(
-        jnp.asarray(adj, jnp.uint8), jnp.asarray(mpred, jnp.int32), dur)
-    return start[:b], finish[:b], level[:b], n_done[:b], q[:b]
+        jnp.asarray(graph), jnp.asarray(graph.T.copy()),
+        pad(mpred, -1, jnp.int32), pad(msucc, -1, jnp.int32),
+        pad(dur, 0, dur.dtype))
+    return (start[:, :n_b], finish[:, :n_b], level[:, :n_b], n_done,
+            q[:, :n_b])
 
 
 # --------------------------------------------------------------------------- #
@@ -358,17 +380,13 @@ def sweep_pallas(adj, dur, mpred, n_valid: int, *, tails: bool = True,
 # --------------------------------------------------------------------------- #
 def default_impl() -> str:
     """``pallas`` on TPU, the XLA gather lowering elsewhere (CPU/GPU)."""
-    try:
-        import jax
+    import jax
 
-        platform = jax.default_backend()
-    except Exception:  # pragma: no cover - jax resolved upstream of callers
-        return "xla"
-    return "pallas" if platform == "tpu" else "xla"
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def sweep(graph: DenseGraph, dur, mpred, msucc, *, tails: bool = True,
-          impl: str | None = None, block_rows: int = 8):
+          impl: str | None = None):
     """Run the sweep with the requested implementation.
 
     ``impl`` ∈ {"xla", "pallas", "pallas_interpret", None=auto}.  ``dur``,
@@ -381,7 +399,6 @@ def sweep(graph: DenseGraph, dur, mpred, msucc, *, tails: bool = True,
         return sweep_xla(jnp.asarray(graph.pred_mat), jnp.asarray(graph.succ_mat),
                          dur, mpred, msucc, graph.n, tails=tails)
     if impl in ("pallas", "pallas_interpret"):
-        return sweep_pallas(graph.adj, dur, mpred, graph.n, tails=tails,
-                            block_rows=block_rows,
+        return sweep_pallas(graph.adj, dur, mpred, msucc, graph.n, tails=tails,
                             interpret=impl == "pallas_interpret")
     raise ValueError(f"unknown schedule-DP impl {impl!r}")

@@ -16,7 +16,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
 from ..kernels import ops
-from ..sharding import get_mesh, shard, shard_map
+from ..sharding import get_mesh, shard
 from .common import ParamDef, apply_rope, checkpoint_name
 
 __all__ = [
@@ -212,7 +212,7 @@ def decode_attention(
         )
         out_specs = (P(b_ax, None, None, None), P(b_ax, "model", None, None),
                      P(b_ax, "model", None, None))
-        out, new_k, new_v = shard_map(
+        out, new_k, new_v = jax.shard_map(
             shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )(q1, cache["k"], cache["v"], kn, vn, pos, slot)

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..sharding import shard_map
 
 __all__ = ["pipeline_apply"]
 
@@ -73,7 +72,7 @@ def pipeline_apply(
         return outs[None]  # (1, n_micro, mb, ...) per stage
 
     n_extra = x_mb.ndim - 1
-    out = shard_map(
+    out = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(stage_axis), P(*([None] * (1 + n_extra)))),

@@ -1,44 +1,45 @@
-"""Persistent JAX compilation-cache wiring.
+"""Persistent JAX compilation cache: one placement rule for every entry point.
 
-One switch shared by the serve warm pool, ``benchmarks/search_bench.py``,
-``benchmarks/serve_bench.py``, and CI (which keys an ``actions/cache``
-entry on the directory): point ``jax_compilation_cache_dir`` at a path so
-compiled launches survive process restarts — the cold ~21s/bucket compile
-becomes a warm disk load on the second run.
+``chip_smoke.py``, the serve engine and both benches
+(``benchmarks/search_bench.py``, ``benchmarks/serve_bench.py``) call
+:func:`enable_compilation_cache` before their first compile, so a device
+launch that took minutes to compile is loaded from disk on the next run.
+The rule:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself, and no
+  directory is set in code, not even one a caller passes;
+* otherwise the caller's ``path``, or else :data:`DEFAULT_DIR`
+  (``<checkout>/.jax_cache``).  The default is fixed on purpose: the cache
+  is found again only at the same path, so a directory derived from a
+  temporary name, a pid or the time never hits.
+
+Either way the min-compile-time and min-entry-size floors drop to 0/-1, so
+small serve launches persist too.
 """
 from __future__ import annotations
 
+import os
 from pathlib import Path
 
-__all__ = ["enable_compilation_cache"]
+__all__ = ["DEFAULT_DIR", "ENV_VAR", "enable_compilation_cache"]
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# src/repro/serve/compile_cache.py -> the checkout root
+DEFAULT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable_compilation_cache(path) -> bool:
-    """Point JAX's persistent compilation cache at ``path`` (created if
-    missing) and drop the min-compile-time / min-entry-size floors so even
-    small serve launches persist.  Returns ``False`` — changing nothing —
-    when JAX is absent or this build lacks the cache knob; callers treat
-    the persistent cache as strictly best-effort."""
-    try:
-        import jax
-    # lint: allow[RPR303] DESIGN §13: best-effort cache wiring outside
-    # the request path — no ReproError can flow here
-    except Exception:
-        return False
-    p = Path(path)
-    p.mkdir(parents=True, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(p))
-    # lint: allow[RPR303] DESIGN §13: best-effort cache knob on a jax
-    # build without it; no request in flight
-    except Exception:
-        return False
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        # lint: allow[RPR303] DESIGN §13: optional floor knobs on older
-        # jax; cache still works, no request in flight
-        except Exception:
-            pass  # older jax: floors stay at defaults; the cache still works
-    return True
+def enable_compilation_cache(path=None) -> str:
+    """Turn JAX's persistent compilation cache on; returns its directory."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    directory = os.environ.get(ENV_VAR)
+    if not directory:
+        directory = str(path or DEFAULT_DIR)
+        Path(directory).mkdir(parents=True, exist_ok=True)
+        if jax.config.jax_compilation_cache_dir != directory:
+            jax.config.update("jax_compilation_cache_dir", directory)
+            cc.reset_cache()  # drop a cache this process opened elsewhere
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return directory

@@ -17,7 +17,7 @@ identity guarantees they cannot perturb real lanes), so a handful of
 compiled programs per signature covers every cut width.  ``warmup``
 pre-compiles those programs from declared :class:`WarmSpec` traffic
 classes via ``device_search.warm_launches`` — backed by the launch LRU
-and, when ``compilation_cache_dir`` is set, JAX's persistent cache.
+and JAX's persistent cache (placed by ``serve.compile_cache``).
 """
 from __future__ import annotations
 
@@ -54,7 +54,9 @@ class EngineConfig:
     but coarsens anytime-incumbent granularity and budget precision (see
     DESIGN.md §11).  ``crit_cap=None`` means full capacity (``batch.n_b``:
     no overflow relaunches under traffic).  ``batch_sizes`` are the
-    quantized vmap widths the warm pool compiles.
+    quantized vmap widths the warm pool compiles.  ``compilation_cache_dir``
+    places the persistent compile cache unless ``JAX_COMPILATION_CACHE_DIR``
+    is set (``serve.compile_cache``; None = the checkout's ``.jax_cache``).
     """
 
     backend: str = "device"  # "device" | "numpy"
@@ -128,8 +130,9 @@ class Engine:
                  params: "TSParams | None" = None):
         self.config = config or EngineConfig()
         self.params = params or TSParams()
-        self.persistent_cache = False
-        if self.config.compilation_cache_dir:
+        # the directory of JAX's persistent compile cache (device backend)
+        self.persistent_cache = None
+        if self.config.backend == "device":
             self.persistent_cache = enable_compilation_cache(
                 self.config.compilation_cache_dir)
         self.warm_info: dict = {}

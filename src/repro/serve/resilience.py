@@ -31,11 +31,14 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import logging
 
 from ..faults.errors import QueueOverload, ReproError, wrap_error
 
 __all__ = ["RetryPolicy", "AdmissionPolicy", "ResiliencePolicy", "Decision",
            "ResilienceController"]
+
+_log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,6 +136,12 @@ class ResilienceController:
             self.sig_failures[signature] += 1
             if self.sig_failures[signature] >= pol.poison_after:
                 self.poisoned.add(signature)
+                # the fallback keeps serving, so say loudly that this launch
+                # class has left the device
+                _log.warning(
+                    "launch signature %r poisoned after %d failures "
+                    "(last: %s); its requests now run on the numpy backend",
+                    signature, self.sig_failures[signature], err)
         if not err.retryable:
             self.n_failed += 1
             return Decision("fail", error=err)

@@ -4,7 +4,6 @@ from .partitioning import (
     make_rules,
     param_rules,
     shard,
-    shard_map,
     set_mesh,
     get_mesh,
 )
@@ -15,7 +14,6 @@ __all__ = [
     "make_rules",
     "param_rules",
     "shard",
-    "shard_map",
     "set_mesh",
     "get_mesh",
 ]

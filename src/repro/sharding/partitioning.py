@@ -27,19 +27,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 _MESH: Mesh | None = None
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: new jax exposes it as ``jax.shard_map``
-    (kwarg ``check_vma``); older releases keep it in ``jax.experimental``
-    with the kwarg spelled ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 def set_mesh(mesh: Mesh | None) -> None:
     global _MESH
     _MESH = mesh

@@ -171,8 +171,8 @@ def test_jax_backend_close_to_numpy():
 def test_unavailable_jax_falls_back(monkeypatch):
     import repro.core.eval_batch as eb
 
+    """backend='jax' without JAX is an error, never a silent numpy run."""
     monkeypatch.setattr(eb, "_jax_available", lambda: False)
     inst = random_instance(0, n_tasks=10, n_data=20)
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        eng = BatchEvaluator(inst, backend="jax")
-    assert eng.backend == "numpy"
+    with pytest.raises(ImportError, match="backend='jax' needs jax"):
+        BatchEvaluator(inst, backend="jax")

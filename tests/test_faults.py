@@ -312,6 +312,26 @@ def test_resume_rejects_wrong_instance(tmp_path):
                          params, config=cfg, resume_from=ckpts[0])
 
 
+def test_poisoning_a_signature_logs_a_warning(caplog):
+    """The numpy fallback keeps serving a poisoned signature, so the switch
+    must be visible in the log."""
+    import logging
+
+    from repro.serve import ResilienceController, ResiliencePolicy, RetryPolicy
+
+    ctl = ResilienceController(ResiliencePolicy(
+        retry=RetryPolicy(max_attempts=5, poison_after=2)))
+    sig = ("bucket", 4)
+    with caplog.at_level(logging.WARNING, logger="repro.serve.resilience"):
+        for attempt in (1, 2):
+            assert not ctl.use_fallback(sig)
+            ctl.on_failure(rid=7, signature=sig, attempts=attempt,
+                           exc=LaunchFailure("launch lost", rid=7), now=0.0)
+    assert ctl.use_fallback(sig)
+    poisoned = [r for r in caplog.records if "poisoned" in r.getMessage()]
+    assert len(poisoned) == 1 and poisoned[0].levelno == logging.WARNING
+
+
 # --------------------------------------------------------------------------- #
 # service integration under an active plan                                    #
 # --------------------------------------------------------------------------- #

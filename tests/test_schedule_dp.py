@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-from jax.experimental import enable_x64  # noqa: E402
+from jax import enable_x64  # noqa: E402
 
 from repro.core import random_instance  # noqa: E402
 from repro.core.eval_batch import BatchEvaluator, pack_solutions  # noqa: E402

@@ -380,3 +380,66 @@ def test_service_device_parity_with_solo_solve():
                                       solo.solution.assign)
         np.testing.assert_array_equal(rr.report.solution.mem,
                                       solo.solution.mem)
+
+
+# --------------------------------------------------------------------------- #
+# persistent compile cache placement
+# --------------------------------------------------------------------------- #
+
+def test_compile_cache_env_var_wins_over_a_path_given_in_code(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, compiles land there and nowhere
+    else, whatever directory the caller names (a fresh process: JAX reads
+    the variable as it is imported)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    import repro
+
+    env_dir, code_dir = tmp_path / "from_env", tmp_path / "from_code"
+    script = (
+        "import jax\n"
+        "from repro.serve import enable_compilation_cache\n"
+        f"print(enable_compilation_cache({str(code_dir)!r}))\n"
+        "jax.jit(lambda x: x * 3 + 1)(2.0).block_until_ready()\n")
+    src = str(Path(repro.__file__).resolve().parents[1])
+    env = {**os.environ, "JAX_COMPILATION_CACHE_DIR": str(env_dir),
+           "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": os.pathsep.join(
+               [src, os.environ.get("PYTHONPATH", "")])}
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == str(env_dir)
+    assert any(p.name.startswith("jit_") for p in env_dir.iterdir())
+    assert not code_dir.exists()
+
+
+def test_compile_cache_defaults_to_one_fixed_path_in_the_checkout(
+        monkeypatch):
+    from pathlib import Path
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    import repro
+    from repro.serve import compile_cache
+
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    knobs = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in knobs}
+    try:
+        first = compile_cache.enable_compilation_cache()
+        again = compile_cache.enable_compilation_cache()
+        checkout = Path(repro.__file__).resolve().parents[2]
+        assert first == again == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
