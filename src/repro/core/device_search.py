@@ -242,6 +242,10 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
     is read off them, so the same body traces for one instance (arrays as
     constants) or under ``vmap`` (arrays as batched tracers).  Returns
     ``run(state, series)``.
+
+    The device ops carry named scopes, which change op metadata only:
+    ``ts_round`` around the loop and, inside a round, ``ts_move_gen``,
+    ``ts_approx_eval``, ``ts_exact_eval``, ``ts_perturb`` and ``ts_commit``.
     """
     import jax
     import jax.numpy as jnp
@@ -382,476 +386,482 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
         mpred, msucc = st["mpred"], st["msucc"]
         cur_mk, best_mk = st["cur_mk"], st["best_mk"]
 
-        dur_all = finish - start
-        q_all = sdp.backward_q_xla(succ_mat, dur_all, msucc, n,
-                                   active0[:, None])
-        r_all = start
-        slack = cur_mk[:, None] - r_all - q_all
-        crit = (slack <= _EPS * jnp.maximum(1.0, cur_mk)[:, None]) \
-            & (jnp.arange(n_b) < n)[None, :] & active0[:, None]
-        crit_count = crit.sum(axis=1)
-        overflow = (active0 & (crit_count > C)).any()
+        # ---------------- critical set, then N7 and change-core moves ----- #
+        with jax.named_scope("ts_move_gen"):
+            dur_all = finish - start
+            q_all = sdp.backward_q_xla(succ_mat, dur_all, msucc, n,
+                                       active0[:, None])
+            r_all = start
+            slack = cur_mk[:, None] - r_all - q_all
+            crit = (slack <= _EPS * jnp.maximum(1.0, cur_mk)[:, None]) \
+                & (jnp.arange(n_b) < n)[None, :] & active0[:, None]
+            crit_count = crit.sum(axis=1)
+            overflow = (active0 & (crit_count > C)).any()
 
-        # ---------------- move generation (N7) -------------------------- #
-        col = jnp.arange(s_b)[None, None, :]
-        validp = col < seq_len[:, :, None]
-        seq_c = jnp.clip(seq, 0, n_b - 1)
-        c_on = jnp.where(validp, take_w(crit, seq_c.reshape(W, -1)
-                                        ).reshape(W, p_b, s_b), False)
-        prev = jnp.pad(c_on[:, :, :-1], ((0, 0), (0, 0), (1, 0)))
-        nxt = jnp.pad(c_on[:, :, 1:], ((0, 0), (0, 0), (0, 1)))
-        starts_m = c_on & ~prev
-        ends_m = c_on & ~nxt
-        sidx = jnp.broadcast_to(jnp.arange(s_b)[None, None, :], c_on.shape)
-        lo_run = jax.lax.cummax(jnp.where(starts_m, sidx, -1), axis=2)
-        hi_run = jax.lax.cummin(jnp.where(ends_m, sidx, s_b + 7), axis=2,
-                                reverse=True)
-        keep = c_on & (hi_run - lo_run >= 1)
-        flat_keep = keep.reshape(W, p_b * s_b)
-        order_n7 = jnp.argsort(~flat_keep, axis=1, stable=True)[:, :C]
-        slot_ok = jnp.take_along_axis(flat_keep, order_n7, axis=1)
-        pp_n7 = (order_n7 // s_b).astype(_I32)
-        ss_n7 = (order_n7 % s_b).astype(_I32)
-        u_n7 = jnp.take_along_axis(seq_c.reshape(W, -1), order_n7, axis=1)
-        lo_n7 = jnp.take_along_axis(lo_run.reshape(W, -1), order_n7, axis=1)
-        hi_n7 = jnp.take_along_axis(hi_run.reshape(W, -1), order_n7, axis=1)
-        # two moves per slot: [to-head, to-tail] interleaved
-        n7_task = jnp.repeat(u_n7, 2, axis=1)
-        n7_src_p = jnp.repeat(pp_n7, 2, axis=1)
-        n7_src_s = jnp.repeat(ss_n7, 2, axis=1)
-        n7_dst = jnp.stack([lo_n7, hi_n7], axis=2).reshape(W, M_n7)
-        n7_valid = jnp.stack(
-            [slot_ok & (ss_n7 != lo_n7), slot_ok & (ss_n7 != hi_n7)],
-            axis=2).reshape(W, M_n7)
+            # ---------------- move generation (N7) -------------------------- #
+            col = jnp.arange(s_b)[None, None, :]
+            validp = col < seq_len[:, :, None]
+            seq_c = jnp.clip(seq, 0, n_b - 1)
+            c_on = jnp.where(validp, take_w(crit, seq_c.reshape(W, -1)
+                                            ).reshape(W, p_b, s_b), False)
+            prev = jnp.pad(c_on[:, :, :-1], ((0, 0), (0, 0), (1, 0)))
+            nxt = jnp.pad(c_on[:, :, 1:], ((0, 0), (0, 0), (0, 1)))
+            starts_m = c_on & ~prev
+            ends_m = c_on & ~nxt
+            sidx = jnp.broadcast_to(jnp.arange(s_b)[None, None, :], c_on.shape)
+            lo_run = jax.lax.cummax(jnp.where(starts_m, sidx, -1), axis=2)
+            hi_run = jax.lax.cummin(jnp.where(ends_m, sidx, s_b + 7), axis=2,
+                                    reverse=True)
+            keep = c_on & (hi_run - lo_run >= 1)
+            flat_keep = keep.reshape(W, p_b * s_b)
+            order_n7 = jnp.argsort(~flat_keep, axis=1, stable=True)[:, :C]
+            slot_ok = jnp.take_along_axis(flat_keep, order_n7, axis=1)
+            pp_n7 = (order_n7 // s_b).astype(_I32)
+            ss_n7 = (order_n7 % s_b).astype(_I32)
+            u_n7 = jnp.take_along_axis(seq_c.reshape(W, -1), order_n7, axis=1)
+            lo_n7 = jnp.take_along_axis(lo_run.reshape(W, -1), order_n7, axis=1)
+            hi_n7 = jnp.take_along_axis(hi_run.reshape(W, -1), order_n7, axis=1)
+            # two moves per slot: [to-head, to-tail] interleaved
+            n7_task = jnp.repeat(u_n7, 2, axis=1)
+            n7_src_p = jnp.repeat(pp_n7, 2, axis=1)
+            n7_src_s = jnp.repeat(ss_n7, 2, axis=1)
+            n7_dst = jnp.stack([lo_n7, hi_n7], axis=2).reshape(W, M_n7)
+            n7_valid = jnp.stack(
+                [slot_ok & (ss_n7 != lo_n7), slot_ok & (ss_n7 != hi_n7)],
+                axis=2).reshape(W, M_n7)
 
-        # ---------------- move generation (change-core) ----------------- #
-        crit_order = jnp.argsort(~crit, axis=1, stable=True)[:, :C]   # (W, C)
-        crit_ok = jnp.take_along_axis(crit, crit_order, axis=1)
-        u_cc = crit_order.astype(_I32)
-        mach, pos = seq_positions(seq, seq_len)
-        a_cc = take_w(mach, u_cc)                                     # (W, C)
-        k_cc = take_w(pos, u_cc)
-        r_starts = jnp.where(validp, take_w(r_all, seq_c.reshape(W, -1)
-                                            ).reshape(W, p_b, s_b), INF)
-        r_u = take_w(r_all, u_cc)                                     # (W, C)
-        anchor = jax.vmap(jax.vmap(jnp.searchsorted, in_axes=(0, None)),
-                          in_axes=(0, 0))(r_starts, r_u)              # (W, p_b, C)
-        anchor = jnp.moveaxis(anchor, 1, 2)                           # (W, C, p_b)
-        lo = jnp.maximum(0, anchor - NPOS // 2)
-        hi = jnp.minimum(seq_len[:, None, :], lo + NPOS)
-        jj = lo[..., None] + jnp.arange(NPOS + 1)[None, None, None, :]
-        cc_valid = (jj <= hi[..., None]) \
-            & crit_ok[:, :, None, None] \
-            & compat[jnp.clip(u_cc, 0, n_b - 1)][..., None] \
-            & (jnp.arange(p_b)[None, None, :, None] != a_cc[:, :, None, None]) \
-            & (jnp.arange(p_b)[None, None, :, None] < p)
-        cc_task = jnp.broadcast_to(u_cc[:, :, None, None], jj.shape)
-        cc_src_p = jnp.broadcast_to(a_cc[:, :, None, None], jj.shape)
-        cc_src_s = jnp.broadcast_to(k_cc[:, :, None, None], jj.shape)
-        cc_dst_p = jnp.broadcast_to(
-            jnp.arange(p_b, dtype=_I32)[None, None, :, None], jj.shape)
+            # ---------------- move generation (change-core) ----------------- #
+            crit_order = jnp.argsort(~crit, axis=1, stable=True)[:, :C]   # (W, C)
+            crit_ok = jnp.take_along_axis(crit, crit_order, axis=1)
+            u_cc = crit_order.astype(_I32)
+            mach, pos = seq_positions(seq, seq_len)
+            a_cc = take_w(mach, u_cc)                                     # (W, C)
+            k_cc = take_w(pos, u_cc)
+            r_starts = jnp.where(validp, take_w(r_all, seq_c.reshape(W, -1)
+                                                ).reshape(W, p_b, s_b), INF)
+            r_u = take_w(r_all, u_cc)                                     # (W, C)
+            anchor = jax.vmap(jax.vmap(jnp.searchsorted, in_axes=(0, None)),
+                              in_axes=(0, 0))(r_starts, r_u)              # (W, p_b, C)
+            anchor = jnp.moveaxis(anchor, 1, 2)                           # (W, C, p_b)
+            lo = jnp.maximum(0, anchor - NPOS // 2)
+            hi = jnp.minimum(seq_len[:, None, :], lo + NPOS)
+            jj = lo[..., None] + jnp.arange(NPOS + 1)[None, None, None, :]
+            cc_valid = (jj <= hi[..., None]) \
+                & crit_ok[:, :, None, None] \
+                & compat[jnp.clip(u_cc, 0, n_b - 1)][..., None] \
+                & (jnp.arange(p_b)[None, None, :, None] != a_cc[:, :, None, None]) \
+                & (jnp.arange(p_b)[None, None, :, None] < p)
+            cc_task = jnp.broadcast_to(u_cc[:, :, None, None], jj.shape)
+            cc_src_p = jnp.broadcast_to(a_cc[:, :, None, None], jj.shape)
+            cc_src_s = jnp.broadcast_to(k_cc[:, :, None, None], jj.shape)
+            cc_dst_p = jnp.broadcast_to(
+                jnp.arange(p_b, dtype=_I32)[None, None, :, None], jj.shape)
 
-        mv_task = jnp.concatenate(
-            [n7_task, cc_task.reshape(W, M_cc)], axis=1).astype(_I32)
-        mv_src_p = jnp.concatenate(
-            [n7_src_p, cc_src_p.reshape(W, M_cc)], axis=1).astype(_I32)
-        mv_src_s = jnp.concatenate(
-            [n7_src_s, cc_src_s.reshape(W, M_cc)], axis=1).astype(_I32)
-        mv_dst_p = jnp.concatenate(
-            [n7_src_p, cc_dst_p.reshape(W, M_cc)], axis=1).astype(_I32)
-        mv_dst_s = jnp.concatenate(
-            [n7_dst, jj.reshape(W, M_cc)], axis=1).astype(_I32)
-        mv_cc = jnp.concatenate(
-            [jnp.zeros((W, M_n7), bool), jnp.ones((W, M_cc), bool)], axis=1)
-        mv_valid = jnp.concatenate(
-            [n7_valid, cc_valid.reshape(W, M_cc)], axis=1) & active0[:, None]
-        n_moves = mv_valid.sum(axis=1)
-        participates = active0 & (n_moves > 0)
-        n_approx = st["n_approx"] + jnp.where(active0, n_moves, 0).sum()
+            mv_task = jnp.concatenate(
+                [n7_task, cc_task.reshape(W, M_cc)], axis=1).astype(_I32)
+            mv_src_p = jnp.concatenate(
+                [n7_src_p, cc_src_p.reshape(W, M_cc)], axis=1).astype(_I32)
+            mv_src_s = jnp.concatenate(
+                [n7_src_s, cc_src_s.reshape(W, M_cc)], axis=1).astype(_I32)
+            mv_dst_p = jnp.concatenate(
+                [n7_src_p, cc_dst_p.reshape(W, M_cc)], axis=1).astype(_I32)
+            mv_dst_s = jnp.concatenate(
+                [n7_dst, jj.reshape(W, M_cc)], axis=1).astype(_I32)
+            mv_cc = jnp.concatenate(
+                [jnp.zeros((W, M_n7), bool), jnp.ones((W, M_cc), bool)], axis=1)
+            mv_valid = jnp.concatenate(
+                [n7_valid, cc_valid.reshape(W, M_cc)], axis=1) & active0[:, None]
+            n_moves = mv_valid.sum(axis=1)
+            participates = active0 & (n_moves > 0)
+            n_approx = st["n_approx"] + jnp.where(active0, n_moves, 0).sum()
 
-        # sanitize masked slots so downstream gathers stay in bounds
-        mv_task = jnp.where(mv_valid, mv_task, 0)
-        mv_src_p = jnp.where(mv_valid, mv_src_p, 0)
-        mv_src_s = jnp.where(mv_valid, mv_src_s, 0)
-        mv_dst_p = jnp.where(mv_valid, mv_dst_p, 0)
-        mv_dst_s = jnp.where(mv_valid, mv_dst_s, 0)
+            # sanitize masked slots so downstream gathers stay in bounds
+            mv_task = jnp.where(mv_valid, mv_task, 0)
+            mv_src_p = jnp.where(mv_valid, mv_src_p, 0)
+            mv_src_s = jnp.where(mv_valid, mv_src_s, 0)
+            mv_dst_p = jnp.where(mv_valid, mv_dst_p, 0)
+            mv_dst_s = jnp.where(mv_valid, mv_dst_s, 0)
 
         # ---------------- approximate evaluation ------------------------ #
-        seq_dst = jnp.take_along_axis(
-            seq, mv_dst_p[:, :, None], axis=1)                        # (W, M, s_b)
-        dur_u = take_w(dur_all, mv_task)
-        q_u = take_w(q_all, mv_task)
-        t_in_cc = reprice(mem, mv_task, mv_dst_p, in_blk)
-        t_out_cc = reprice(mem, mv_task, mv_dst_p, out_blk)
-        d_cc = t_in_cc + proc_time[mv_task, mv_dst_p] + t_out_cc
-        dur_u = jnp.where(mv_cc, d_cc, dur_u)
-        q_u = jnp.where(mv_cc, take_w(q_all, mv_task)
-                        - take_w(dur_all, mv_task) + d_cc, q_u)
-        finite = jnp.isfinite(dur_u)
-        dst_len = jnp.take_along_axis(seq_len, mv_dst_p, axis=1)
-        new_len = dst_len + mv_cc
-        w_lo = jnp.where(mv_cc, mv_dst_s, jnp.minimum(mv_src_s, mv_dst_s))
-        w_hi = jnp.minimum(new_len, w_lo + WIN)
-        est = jnp.zeros((W, M), f64)
-        xp = jnp.take_along_axis(
-            seq_dst, jnp.clip(w_lo - 1, 0, s_b - 1)[..., None], axis=2)[..., 0]
-        xp = jnp.clip(xp, 0, n_b - 1)
-        prev_finish = jnp.where(
-            w_lo > 0, take_w(r_all, xp) + take_w(dur_all, xp), 0.0)
-        win_of = jnp.full((W, M, n_b + 1), -1, jnp.int8)
-        win_heads = jnp.zeros((W, M, WIN), f64)
-        mi = jnp.arange(M)[None, :]
-        wim = jnp.broadcast_to(wi[:, None], (W, M))
-        for s in range(WIN):
-            idxp = w_lo + s
-            act = mv_valid & (idxp < w_hi)
-            x = new_seq_at(seq_dst, mv_task, mv_dst_s, mv_src_s, mv_cc, idxp)
-            x = jnp.where(act, x, 0)
-            preds = pred_mat[x]                                       # (W, M, Dp)
-            pok = preds >= 0
-            psafe = jnp.where(pok, preds, n_b)
-            tpos = jnp.take_along_axis(win_of, psafe, axis=2)         # (W, M, Dp)
-            in_win = tpos >= 0
-            head_at = jnp.take_along_axis(
-                win_heads, jnp.clip(tpos, 0, WIN - 1).astype(jnp.int32), axis=2)
-            pclip = jnp.clip(preds, 0, n_b - 1)
-            dsel = jnp.where(preds == mv_task[..., None],
-                             dur_u[..., None], take_w(dur_all, pclip))
-            f_win = head_at + dsel
-            f_def = take_w(r_all, pclip) + take_w(dur_all, pclip)
-            f = jnp.where(pok, jnp.where(in_win, f_win, f_def), -INF)
-            head = jnp.maximum(prev_finish, f.max(axis=2))
-            win_of = win_of.at[wim, mi, jnp.where(act, x, n_b)].set(
-                jnp.int8(s))
-            win_heads = win_heads.at[:, :, s].set(head)
-            is_u = x == mv_task
-            dx = jnp.where(is_u, dur_u, take_w(dur_all, x))
-            qx = jnp.where(is_u, q_u, take_w(q_all, x))
-            est = jnp.where(act, jnp.maximum(est, head + qx), est)
-            prev_finish = jnp.where(act, head + dx, prev_finish)
-        tailm = mv_valid & (w_hi < new_len)
-        x_t = new_seq_at(seq_dst, mv_task, mv_dst_s, mv_src_s, mv_cc, w_hi)
-        x_t = jnp.clip(jnp.where(tailm, x_t, 0), 0, n_b - 1)
-        est = jnp.where(tailm,
-                        jnp.maximum(est, prev_finish + take_w(q_all, x_t)),
-                        est)
-        est = jnp.where(finite & mv_valid, est, INF)
+        with jax.named_scope("ts_approx_eval"):
+            seq_dst = jnp.take_along_axis(
+                seq, mv_dst_p[:, :, None], axis=1)                        # (W, M, s_b)
+            dur_u = take_w(dur_all, mv_task)
+            q_u = take_w(q_all, mv_task)
+            t_in_cc = reprice(mem, mv_task, mv_dst_p, in_blk)
+            t_out_cc = reprice(mem, mv_task, mv_dst_p, out_blk)
+            d_cc = t_in_cc + proc_time[mv_task, mv_dst_p] + t_out_cc
+            dur_u = jnp.where(mv_cc, d_cc, dur_u)
+            q_u = jnp.where(mv_cc, take_w(q_all, mv_task)
+                            - take_w(dur_all, mv_task) + d_cc, q_u)
+            finite = jnp.isfinite(dur_u)
+            dst_len = jnp.take_along_axis(seq_len, mv_dst_p, axis=1)
+            new_len = dst_len + mv_cc
+            w_lo = jnp.where(mv_cc, mv_dst_s, jnp.minimum(mv_src_s, mv_dst_s))
+            w_hi = jnp.minimum(new_len, w_lo + WIN)
+            est = jnp.zeros((W, M), f64)
+            xp = jnp.take_along_axis(
+                seq_dst, jnp.clip(w_lo - 1, 0, s_b - 1)[..., None], axis=2)[..., 0]
+            xp = jnp.clip(xp, 0, n_b - 1)
+            prev_finish = jnp.where(
+                w_lo > 0, take_w(r_all, xp) + take_w(dur_all, xp), 0.0)
+            win_of = jnp.full((W, M, n_b + 1), -1, jnp.int8)
+            win_heads = jnp.zeros((W, M, WIN), f64)
+            mi = jnp.arange(M)[None, :]
+            wim = jnp.broadcast_to(wi[:, None], (W, M))
+            for s in range(WIN):
+                idxp = w_lo + s
+                act = mv_valid & (idxp < w_hi)
+                x = new_seq_at(seq_dst, mv_task, mv_dst_s, mv_src_s, mv_cc, idxp)
+                x = jnp.where(act, x, 0)
+                preds = pred_mat[x]                                       # (W, M, Dp)
+                pok = preds >= 0
+                psafe = jnp.where(pok, preds, n_b)
+                tpos = jnp.take_along_axis(win_of, psafe, axis=2)         # (W, M, Dp)
+                in_win = tpos >= 0
+                head_at = jnp.take_along_axis(
+                    win_heads, jnp.clip(tpos, 0, WIN - 1).astype(jnp.int32), axis=2)
+                pclip = jnp.clip(preds, 0, n_b - 1)
+                dsel = jnp.where(preds == mv_task[..., None],
+                                 dur_u[..., None], take_w(dur_all, pclip))
+                f_win = head_at + dsel
+                f_def = take_w(r_all, pclip) + take_w(dur_all, pclip)
+                f = jnp.where(pok, jnp.where(in_win, f_win, f_def), -INF)
+                head = jnp.maximum(prev_finish, f.max(axis=2))
+                win_of = win_of.at[wim, mi, jnp.where(act, x, n_b)].set(
+                    jnp.int8(s))
+                win_heads = win_heads.at[:, :, s].set(head)
+                is_u = x == mv_task
+                dx = jnp.where(is_u, dur_u, take_w(dur_all, x))
+                qx = jnp.where(is_u, q_u, take_w(q_all, x))
+                est = jnp.where(act, jnp.maximum(est, head + qx), est)
+                prev_finish = jnp.where(act, head + dx, prev_finish)
+            tailm = mv_valid & (w_hi < new_len)
+            x_t = new_seq_at(seq_dst, mv_task, mv_dst_s, mv_src_s, mv_cc, w_hi)
+            x_t = jnp.clip(jnp.where(tailm, x_t, 0), 0, n_b - 1)
+            est = jnp.where(tailm,
+                            jnp.maximum(est, prev_finish + take_w(q_all, x_t)),
+                            est)
+            est = jnp.where(finite & mv_valid, est, INF)
 
-        # ---------------- sort, tabu pre-filter ------------------------- #
-        order = jnp.argsort(est, axis=1, stable=True)
-        est_s = jnp.take_along_axis(est, order, axis=1)
-        task_s = jnp.take_along_axis(mv_task, order, axis=1)
-        srcp_s = jnp.take_along_axis(mv_src_p, order, axis=1)
-        srcs_s = jnp.take_along_axis(mv_src_s, order, axis=1)
-        dstp_s = jnp.take_along_axis(mv_dst_p, order, axis=1)
-        dsts_s = jnp.take_along_axis(mv_dst_s, order, axis=1)
-        cc_s = jnp.take_along_axis(mv_cc, order, axis=1)
-        valid_s = jnp.take_along_axis(mv_valid & finite, order, axis=1)
-        # resulting configuration (task, dst_proc, machine-pred-after-move)
-        seq_dst_s = jnp.take_along_axis(seq, dstp_s[:, :, None], axis=1)
-        pi = dsts_s - 1
-        pio = pi + ((~cc_s) & (pi >= srcs_s))
-        pred_cfg = jnp.where(
-            pi >= 0,
-            jnp.take_along_axis(seq_dst_s,
-                                jnp.clip(pio, 0, s_b - 1)[..., None],
-                                axis=2)[..., 0],
-            -2)
-        cfg_idx = (task_s.astype(jnp.int64) * p_b + dstp_s) * (n_b + 2) \
-            + (pred_cfg + 2)
-        expiry = jnp.take_along_axis(
-            st["tabu"], jnp.clip(cfg_idx, 0, st["tabu"].shape[1] - 1), axis=1)
-        is_tabu = expiry >= it
-        adm = valid_s & ~(is_tabu & (est_s >= best_mk[:, None]))
-        n_adm = adm.sum(axis=1)
-        adm_perm = jnp.argsort(~adm, axis=1, stable=True)
-        # compact admissible move attributes, in est order
-        def comp(a):
-            return jnp.take_along_axis(a, adm_perm, axis=1)
-        c_task, c_srcp, c_srcs, c_dstp, c_dsts, c_cc, c_tabu = (
-            comp(task_s), comp(srcp_s), comp(srcs_s), comp(dstp_s),
-            comp(dsts_s), comp(cc_s), comp(is_tabu))
+            # ---------------- sort, tabu pre-filter ------------------------- #
+            order = jnp.argsort(est, axis=1, stable=True)
+            est_s = jnp.take_along_axis(est, order, axis=1)
+            task_s = jnp.take_along_axis(mv_task, order, axis=1)
+            srcp_s = jnp.take_along_axis(mv_src_p, order, axis=1)
+            srcs_s = jnp.take_along_axis(mv_src_s, order, axis=1)
+            dstp_s = jnp.take_along_axis(mv_dst_p, order, axis=1)
+            dsts_s = jnp.take_along_axis(mv_dst_s, order, axis=1)
+            cc_s = jnp.take_along_axis(mv_cc, order, axis=1)
+            valid_s = jnp.take_along_axis(mv_valid & finite, order, axis=1)
+            # resulting configuration (task, dst_proc, machine-pred-after-move)
+            seq_dst_s = jnp.take_along_axis(seq, dstp_s[:, :, None], axis=1)
+            pi = dsts_s - 1
+            pio = pi + ((~cc_s) & (pi >= srcs_s))
+            pred_cfg = jnp.where(
+                pi >= 0,
+                jnp.take_along_axis(seq_dst_s,
+                                    jnp.clip(pio, 0, s_b - 1)[..., None],
+                                    axis=2)[..., 0],
+                -2)
+            cfg_idx = (task_s.astype(jnp.int64) * p_b + dstp_s) * (n_b + 2) \
+                + (pred_cfg + 2)
+            expiry = jnp.take_along_axis(
+                st["tabu"], jnp.clip(cfg_idx, 0, st["tabu"].shape[1] - 1), axis=1)
+            is_tabu = expiry >= it
+            adm = valid_s & ~(is_tabu & (est_s >= best_mk[:, None]))
+            n_adm = adm.sum(axis=1)
+            adm_perm = jnp.argsort(~adm, axis=1, stable=True)
+            # compact admissible move attributes, in est order
+            def comp(a):
+                return jnp.take_along_axis(a, adm_perm, axis=1)
+            c_task, c_srcp, c_srcs, c_dstp, c_dsts, c_cc, c_tabu = (
+                comp(task_s), comp(srcp_s), comp(srcs_s), comp(dstp_s),
+                comp(dsts_s), comp(cc_s), comp(is_tabu))
 
         # ---------------- chunked top-K exact evaluation ----------------- #
-        def apply_and_eval(sel_idx, slot_ok, *, arrs=None):
-            """sel_idx (W, kk) indices into a move-array bundle — by default
-            the compact admissible arrays (top-K chunks); the perturbation
-            path passes the raw unsorted arrays instead and reuses this
-            exact splice arithmetic at width 1."""
-            task_a, srcs_a, dstp_a, dsts_a, cc_a = arrs if arrs is not None \
-                else (c_task, c_srcs, c_dstp, c_dsts, c_cc)
-            kk = sel_idx.shape[1]
-            u = jnp.take_along_axis(task_a, sel_idx, axis=1)
-            ksrc = jnp.take_along_axis(srcs_a, sel_idx, axis=1)
-            b = jnp.take_along_axis(dstp_a, sel_idx, axis=1)
-            j = jnp.take_along_axis(dsts_a, sel_idx, axis=1)
-            ccm = jnp.take_along_axis(cc_a, sel_idx, axis=1)
-            u = jnp.where(slot_ok, u, 0)
-            b = jnp.where(slot_ok, b, 0)
-            x = take_w(mpred, u)
-            y = take_w(msucc, u)
-            w3 = jnp.broadcast_to(wi[:, None], (W, kk))
-            k3 = jnp.broadcast_to(jnp.arange(kk)[None, :], (W, kk))
-            mp = jnp.concatenate(
-                [jnp.broadcast_to(mpred[:, None, :], (W, kk, n_b)),
-                 jnp.full((W, kk, 1), -1, _I32)], axis=2)
-            ms = jnp.concatenate(
-                [jnp.broadcast_to(msucc[:, None, :], (W, kk, n_b)),
-                 jnp.full((W, kk, 1), -1, _I32)], axis=2)
-            asg = jnp.concatenate(
-                [jnp.broadcast_to(assign[:, None, :], (W, kk, n_b)),
-                 jnp.zeros((W, kk, 1), _I32)], axis=2)
+        with jax.named_scope("ts_exact_eval"):
+            def apply_and_eval(sel_idx, slot_ok, *, arrs=None):
+                """sel_idx (W, kk) indices into a move-array bundle — by default
+                the compact admissible arrays (top-K chunks); the perturbation
+                path passes the raw unsorted arrays instead and reuses this
+                exact splice arithmetic at width 1."""
+                task_a, srcs_a, dstp_a, dsts_a, cc_a = arrs if arrs is not None \
+                    else (c_task, c_srcs, c_dstp, c_dsts, c_cc)
+                kk = sel_idx.shape[1]
+                u = jnp.take_along_axis(task_a, sel_idx, axis=1)
+                ksrc = jnp.take_along_axis(srcs_a, sel_idx, axis=1)
+                b = jnp.take_along_axis(dstp_a, sel_idx, axis=1)
+                j = jnp.take_along_axis(dsts_a, sel_idx, axis=1)
+                ccm = jnp.take_along_axis(cc_a, sel_idx, axis=1)
+                u = jnp.where(slot_ok, u, 0)
+                b = jnp.where(slot_ok, b, 0)
+                x = take_w(mpred, u)
+                y = take_w(msucc, u)
+                w3 = jnp.broadcast_to(wi[:, None], (W, kk))
+                k3 = jnp.broadcast_to(jnp.arange(kk)[None, :], (W, kk))
+                mp = jnp.concatenate(
+                    [jnp.broadcast_to(mpred[:, None, :], (W, kk, n_b)),
+                     jnp.full((W, kk, 1), -1, _I32)], axis=2)
+                ms = jnp.concatenate(
+                    [jnp.broadcast_to(msucc[:, None, :], (W, kk, n_b)),
+                     jnp.full((W, kk, 1), -1, _I32)], axis=2)
+                asg = jnp.concatenate(
+                    [jnp.broadcast_to(assign[:, None, :], (W, kk, n_b)),
+                     jnp.zeros((W, kk, 1), _I32)], axis=2)
 
-            def safe(t, okm):
-                return jnp.where(okm & slot_ok, t, n_b)
+                def safe(t, okm):
+                    return jnp.where(okm & slot_ok, t, n_b)
 
-            ms = ms.at[w3, k3, safe(x, x >= 0)].set(y)
-            mp = mp.at[w3, k3, safe(y, y >= 0)].set(x)
-            dseq = jnp.take_along_axis(seq, b[:, :, None], axis=1)
-            same = ~ccm
-            len_dst = jnp.take_along_axis(seq_len, b, axis=1) - same
-            pi2 = j - 1
-            pio2 = pi2 + (same & (pi2 >= ksrc))
-            pred_t = jnp.where(
-                pi2 >= 0,
-                jnp.take_along_axis(dseq, jnp.maximum(pio2, 0)[..., None],
-                                    axis=2)[..., 0], -1)
-            sio2 = j + (same & (j >= ksrc))
-            succ_t = jnp.where(
-                j < len_dst,
-                jnp.take_along_axis(dseq,
-                                    jnp.minimum(sio2, s_b - 1)[..., None],
-                                    axis=2)[..., 0], -1)
-            mp = mp.at[w3, k3, safe(u, slot_ok)].set(pred_t.astype(_I32))
-            ms = ms.at[w3, k3, safe(u, slot_ok)].set(succ_t.astype(_I32))
-            ms = ms.at[w3, k3, safe(pred_t, pred_t >= 0)].set(u)
-            mp = mp.at[w3, k3, safe(succ_t, succ_t >= 0)].set(u)
-            asg = asg.at[w3, k3, safe(u, slot_ok)].set(b)
-            mem_rows = jnp.broadcast_to(
-                mem[:, None, :], (W, kk, d_b)).reshape(W * kk, d_b)
-            start_c, finish_c, feas, mk = eval_candidates(
-                asg[:, :, :n_b].reshape(W * kk, n_b),
-                mp[:, :, :n_b].reshape(W * kk, n_b), mem_rows)
-            return (start_c.reshape(W, kk, n_b), finish_c.reshape(W, kk, n_b),
-                    feas.reshape(W, kk), mk.reshape(W, kk))
+                ms = ms.at[w3, k3, safe(x, x >= 0)].set(y)
+                mp = mp.at[w3, k3, safe(y, y >= 0)].set(x)
+                dseq = jnp.take_along_axis(seq, b[:, :, None], axis=1)
+                same = ~ccm
+                len_dst = jnp.take_along_axis(seq_len, b, axis=1) - same
+                pi2 = j - 1
+                pio2 = pi2 + (same & (pi2 >= ksrc))
+                pred_t = jnp.where(
+                    pi2 >= 0,
+                    jnp.take_along_axis(dseq, jnp.maximum(pio2, 0)[..., None],
+                                        axis=2)[..., 0], -1)
+                sio2 = j + (same & (j >= ksrc))
+                succ_t = jnp.where(
+                    j < len_dst,
+                    jnp.take_along_axis(dseq,
+                                        jnp.minimum(sio2, s_b - 1)[..., None],
+                                        axis=2)[..., 0], -1)
+                mp = mp.at[w3, k3, safe(u, slot_ok)].set(pred_t.astype(_I32))
+                ms = ms.at[w3, k3, safe(u, slot_ok)].set(succ_t.astype(_I32))
+                ms = ms.at[w3, k3, safe(pred_t, pred_t >= 0)].set(u)
+                mp = mp.at[w3, k3, safe(succ_t, succ_t >= 0)].set(u)
+                asg = asg.at[w3, k3, safe(u, slot_ok)].set(b)
+                mem_rows = jnp.broadcast_to(
+                    mem[:, None, :], (W, kk, d_b)).reshape(W * kk, d_b)
+                start_c, finish_c, feas, mk = eval_candidates(
+                    asg[:, :, :n_b].reshape(W * kk, n_b),
+                    mp[:, :, :n_b].reshape(W * kk, n_b), mem_rows)
+                return (start_c.reshape(W, kk, n_b), finish_c.reshape(W, kk, n_b),
+                        feas.reshape(W, kk), mk.reshape(W, kk))
 
-        def chunk_cond(cs):
-            return cs["live"]
+            def chunk_cond(cs):
+                return cs["live"]
 
-        def chunk_body(cs):
-            pos, examined = cs["pos"], cs["examined"]
-            done = cs["done"] \
-                | (cs["found"] & (examined >= K)) \
-                | (pos >= n_adm)
-            avail = jnp.maximum(max_evals - cs["n_exact"], 0)
-            want = jnp.where(participates & ~done,
-                             jnp.minimum(K, n_adm - pos), 0)
-            # lint: allow[RPR103] DESIGN §9: exclusive prefix over small
-            # nonneg ints is exact regardless of scan order; the §9 parity
-            # hazard is float accumulation, which the blocked scan covers
-            before = jnp.cumsum(want) - want
-            size = jnp.clip(jnp.minimum(want, avail - before), 0, want)
-            done = done | (want > 0) & (size <= 0)
-            live = (size > 0).any()
+            def chunk_body(cs):
+                pos, examined = cs["pos"], cs["examined"]
+                done = cs["done"] \
+                    | (cs["found"] & (examined >= K)) \
+                    | (pos >= n_adm)
+                avail = jnp.maximum(max_evals - cs["n_exact"], 0)
+                want = jnp.where(participates & ~done,
+                                 jnp.minimum(K, n_adm - pos), 0)
+                # lint: allow[RPR103] DESIGN §9: exclusive prefix over small
+                # nonneg ints is exact regardless of scan order; the §9 parity
+                # hazard is float accumulation, which the blocked scan covers
+                before = jnp.cumsum(want) - want
+                size = jnp.clip(jnp.minimum(want, avail - before), 0, want)
+                done = done | (want > 0) & (size <= 0)
+                live = (size > 0).any()
 
-            def do_eval(cs):
-                sel = pos[:, None] + jnp.arange(K)[None, :]
-                slot_ok = jnp.arange(K)[None, :] < size[:, None]
-                sel = jnp.where(slot_ok, jnp.clip(sel, 0, M - 1), 0)
-                start_c, finish_c, feas, mk = apply_and_eval(sel, slot_ok)
-                tabu_slot = jnp.take_along_axis(c_tabu, sel, axis=1)
-                elig = slot_ok & feas \
-                    & ~(tabu_slot & (mk >= best_mk[:, None]))
-                mk_m = jnp.where(elig, mk, INF)
-                jmin = jnp.argmin(mk_m, axis=1)
-                cand_mk = jnp.take_along_axis(mk_m, jmin[:, None], axis=1)[:, 0]
-                better = cand_mk < cs["chosen_mk"]
-                sel_j = jnp.take_along_axis(sel, jmin[:, None], axis=1)[:, 0]
-                ch_start = jnp.take_along_axis(
-                    start_c, jmin[:, None, None], axis=1)[:, 0]
-                ch_finish = jnp.take_along_axis(
-                    finish_c, jmin[:, None, None], axis=1)[:, 0]
-                return {
-                    "pos": pos + size,
-                    "examined": examined + size,
-                    "done": done,
-                    "found": cs["found"] | better,
-                    "chosen_i": jnp.where(better, sel_j, cs["chosen_i"]),
-                    "chosen_mk": jnp.where(better, cand_mk, cs["chosen_mk"]),
-                    "chosen_start": jnp.where(better[:, None], ch_start,
-                                              cs["chosen_start"]),
-                    "chosen_finish": jnp.where(better[:, None], ch_finish,
-                                               cs["chosen_finish"]),
-                    "n_exact": cs["n_exact"] + size.sum(),
-                    "live": live,
-                }
+                def do_eval(cs):
+                    sel = pos[:, None] + jnp.arange(K)[None, :]
+                    slot_ok = jnp.arange(K)[None, :] < size[:, None]
+                    sel = jnp.where(slot_ok, jnp.clip(sel, 0, M - 1), 0)
+                    start_c, finish_c, feas, mk = apply_and_eval(sel, slot_ok)
+                    tabu_slot = jnp.take_along_axis(c_tabu, sel, axis=1)
+                    elig = slot_ok & feas \
+                        & ~(tabu_slot & (mk >= best_mk[:, None]))
+                    mk_m = jnp.where(elig, mk, INF)
+                    jmin = jnp.argmin(mk_m, axis=1)
+                    cand_mk = jnp.take_along_axis(mk_m, jmin[:, None], axis=1)[:, 0]
+                    better = cand_mk < cs["chosen_mk"]
+                    sel_j = jnp.take_along_axis(sel, jmin[:, None], axis=1)[:, 0]
+                    ch_start = jnp.take_along_axis(
+                        start_c, jmin[:, None, None], axis=1)[:, 0]
+                    ch_finish = jnp.take_along_axis(
+                        finish_c, jmin[:, None, None], axis=1)[:, 0]
+                    return {
+                        "pos": pos + size,
+                        "examined": examined + size,
+                        "done": done,
+                        "found": cs["found"] | better,
+                        "chosen_i": jnp.where(better, sel_j, cs["chosen_i"]),
+                        "chosen_mk": jnp.where(better, cand_mk, cs["chosen_mk"]),
+                        "chosen_start": jnp.where(better[:, None], ch_start,
+                                                  cs["chosen_start"]),
+                        "chosen_finish": jnp.where(better[:, None], ch_finish,
+                                                   cs["chosen_finish"]),
+                        "n_exact": cs["n_exact"] + size.sum(),
+                        "live": live,
+                    }
 
-            def no_eval(cs):
-                out = dict(cs)
-                out["done"] = done
-                out["live"] = live
-                return out
+                def no_eval(cs):
+                    out = dict(cs)
+                    out["done"] = done
+                    out["live"] = live
+                    return out
 
-            return jax.lax.cond(live, do_eval, no_eval, cs)
+                return jax.lax.cond(live, do_eval, no_eval, cs)
 
-        chunk0 = {
-            "pos": jnp.zeros(W, jnp.int64),
-            "examined": jnp.zeros(W, jnp.int64),
-            "done": ~participates,
-            "found": jnp.zeros(W, bool),
-            "chosen_i": jnp.zeros(W, jnp.int64),
-            "chosen_mk": jnp.full(W, INF),
-            "chosen_start": jnp.zeros((W, n_b)),
-            "chosen_finish": jnp.zeros((W, n_b)),
-            "n_exact": st["n_exact"],
-            "live": jnp.asarray(True),
-        }
-        cs = jax.lax.while_loop(chunk_cond, chunk_body, chunk0)
-        n_exact = cs["n_exact"]
-        found = cs["found"] & participates
+            chunk0 = {
+                "pos": jnp.zeros(W, jnp.int64),
+                "examined": jnp.zeros(W, jnp.int64),
+                "done": ~participates,
+                "found": jnp.zeros(W, bool),
+                "chosen_i": jnp.zeros(W, jnp.int64),
+                "chosen_mk": jnp.full(W, INF),
+                "chosen_start": jnp.zeros((W, n_b)),
+                "chosen_finish": jnp.zeros((W, n_b)),
+                "n_exact": st["n_exact"],
+                "live": jnp.asarray(True),
+            }
+            cs = jax.lax.while_loop(chunk_cond, chunk_body, chunk0)
+            n_exact = cs["n_exact"]
+            found = cs["found"] & participates
 
         # ---------------- stalled walks: budget stop or perturbation ----- #
-        exhausted = participates & ~found & (n_exact >= max_evals)
-        stop = st["stop"] | exhausted.any()
-        perturb_w = participates & ~found & (n_exact < max_evals) \
-            if cfg.perturb else jnp.zeros(W, bool)
+        with jax.named_scope("ts_perturb"):
+            exhausted = participates & ~found & (n_exact >= max_evals)
+            stop = st["stop"] | exhausted.any()
+            perturb_w = participates & ~found & (n_exact < max_evals) \
+                if cfg.perturb else jnp.zeros(W, bool)
 
-        # perturbation: one threefry-random move per stalled walk, evaluated
-        # as one extra (W, 1) candidate batch through the SAME splice/eval
-        # path as the top-K chunks.  Everything (pick included) lives inside
-        # the cond branch, so unstalled rounds — the overwhelming majority —
-        # pay nothing for it.
-        any_perturb = perturb_w.any()
+            # perturbation: one threefry-random move per stalled walk, evaluated
+            # as one extra (W, 1) candidate batch through the SAME splice/eval
+            # path as the top-K chunks.  Everything (pick included) lives inside
+            # the cond branch, so unstalled rounds — the overwhelming majority —
+            # pay nothing for it.
+            any_perturb = perturb_w.any()
 
-        def perturb_eval(n_exact):
-            fold = (wi.astype(jnp.uint32) * jnp.uint32(131071)
-                    + it.astype(jnp.uint32))
-            sub = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
-                jax.random.wrap_key_data(st["key"]), fold)
-            valid_perm = jnp.argsort(~mv_valid, axis=1, stable=True)
-            ridx = jax.vmap(
-                lambda kk, hi2: jax.random.randint(kk, (), 0, jnp.maximum(hi2, 1)))(
-                sub, n_moves)
-            pick = jnp.take_along_axis(valid_perm, ridx[:, None], axis=1)
-            slot_ok = perturb_w[:, None]
-            start_c, finish_c, feas, mk = apply_and_eval(
-                pick, slot_ok,
-                arrs=(mv_task, mv_src_s, mv_dst_p, mv_dst_s, mv_cc))
-            ok = perturb_w & feas[:, 0]
+            def perturb_eval(n_exact):
+                fold = (wi.astype(jnp.uint32) * jnp.uint32(131071)
+                        + it.astype(jnp.uint32))
+                sub = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+                    jax.random.wrap_key_data(st["key"]), fold)
+                valid_perm = jnp.argsort(~mv_valid, axis=1, stable=True)
+                ridx = jax.vmap(
+                    lambda kk, hi2: jax.random.randint(kk, (), 0, jnp.maximum(hi2, 1)))(
+                    sub, n_moves)
+                pick = jnp.take_along_axis(valid_perm, ridx[:, None], axis=1)
+                slot_ok = perturb_w[:, None]
+                start_c, finish_c, feas, mk = apply_and_eval(
+                    pick, slot_ok,
+                    arrs=(mv_task, mv_src_s, mv_dst_p, mv_dst_s, mv_cc))
+                ok = perturb_w & feas[:, 0]
 
-            def g(a):
-                return jnp.take_along_axis(a, pick, axis=1)[:, 0]
+                def g(a):
+                    return jnp.take_along_axis(a, pick, axis=1)[:, 0]
 
-            return (ok, g(mv_task), g(mv_src_p), g(mv_src_s), g(mv_dst_p),
-                    g(mv_dst_s), g(mv_cc), start_c[:, 0], finish_c[:, 0],
-                    mk[:, 0], n_exact + jnp.where(perturb_w, 1, 0).sum())
+                return (ok, g(mv_task), g(mv_src_p), g(mv_src_s), g(mv_dst_p),
+                        g(mv_dst_s), g(mv_cc), start_c[:, 0], finish_c[:, 0],
+                        mk[:, 0], n_exact + jnp.where(perturb_w, 1, 0).sum())
 
-        def perturb_skip(n_exact):
-            z = jnp.zeros(W, _I32)
-            return (jnp.zeros(W, bool), z, z, z, z, z,
-                    jnp.zeros(W, bool), jnp.zeros((W, n_b)),
-                    jnp.zeros((W, n_b)), jnp.full(W, INF), n_exact)
+            def perturb_skip(n_exact):
+                z = jnp.zeros(W, _I32)
+                return (jnp.zeros(W, bool), z, z, z, z, z,
+                        jnp.zeros(W, bool), jnp.zeros((W, n_b)),
+                        jnp.zeros((W, n_b)), jnp.full(W, INF), n_exact)
 
-        (p_ok, p_u, p_a, p_k, p_b2, p_j, p_cc, p_start, p_finish, p_mk,
-         n_exact) = jax.lax.cond(any_perturb, perturb_eval, perturb_skip,
-                                 n_exact)
+            (p_ok, p_u, p_a, p_k, p_b2, p_j, p_cc, p_start, p_finish, p_mk,
+             n_exact) = jax.lax.cond(any_perturb, perturb_eval, perturb_skip,
+                                     n_exact)
 
         # ---------------- commit (accepted move or feasible perturbation) #
-        commit = found | p_ok
-        cm_u = jnp.where(found, jnp.take_along_axis(
-            c_task, cs["chosen_i"][:, None], axis=1)[:, 0], p_u).astype(_I32)
-        cm_a = jnp.where(found, jnp.take_along_axis(
-            c_srcp, cs["chosen_i"][:, None], axis=1)[:, 0], p_a).astype(_I32)
-        cm_k = jnp.where(found, jnp.take_along_axis(
-            c_srcs, cs["chosen_i"][:, None], axis=1)[:, 0], p_k).astype(_I32)
-        cm_b = jnp.where(found, jnp.take_along_axis(
-            c_dstp, cs["chosen_i"][:, None], axis=1)[:, 0], p_b2).astype(_I32)
-        cm_j = jnp.where(found, jnp.take_along_axis(
-            c_dsts, cs["chosen_i"][:, None], axis=1)[:, 0], p_j).astype(_I32)
-        cm_cc = jnp.where(found, jnp.take_along_axis(
-            c_cc, cs["chosen_i"][:, None], axis=1)[:, 0], p_cc)
-        new_start = jnp.where(found[:, None], cs["chosen_start"],
-                              jnp.where(p_ok[:, None], p_start, start))
-        new_finish = jnp.where(found[:, None], cs["chosen_finish"],
-                               jnp.where(p_ok[:, None], p_finish, finish))
-        new_mk = jnp.where(found, cs["chosen_mk"],
-                           jnp.where(p_ok, p_mk, cur_mk))
+        with jax.named_scope("ts_commit"):
+            commit = found | p_ok
+            cm_u = jnp.where(found, jnp.take_along_axis(
+                c_task, cs["chosen_i"][:, None], axis=1)[:, 0], p_u).astype(_I32)
+            cm_a = jnp.where(found, jnp.take_along_axis(
+                c_srcp, cs["chosen_i"][:, None], axis=1)[:, 0], p_a).astype(_I32)
+            cm_k = jnp.where(found, jnp.take_along_axis(
+                c_srcs, cs["chosen_i"][:, None], axis=1)[:, 0], p_k).astype(_I32)
+            cm_b = jnp.where(found, jnp.take_along_axis(
+                c_dstp, cs["chosen_i"][:, None], axis=1)[:, 0], p_b2).astype(_I32)
+            cm_j = jnp.where(found, jnp.take_along_axis(
+                c_dsts, cs["chosen_i"][:, None], axis=1)[:, 0], p_j).astype(_I32)
+            cm_cc = jnp.where(found, jnp.take_along_axis(
+                c_cc, cs["chosen_i"][:, None], axis=1)[:, 0], p_cc)
+            new_start = jnp.where(found[:, None], cs["chosen_start"],
+                                  jnp.where(p_ok[:, None], p_start, start))
+            new_finish = jnp.where(found[:, None], cs["chosen_finish"],
+                                   jnp.where(p_ok[:, None], p_finish, finish))
+            new_mk = jnp.where(found, cs["chosen_mk"],
+                               jnp.where(p_ok, p_mk, cur_mk))
 
-        # tabu the destroyed configuration (accepted moves only)
-        mp_before = take_w(mpred, cm_u[:, None])[:, 0]
-        destroyed = (cm_u.astype(jnp.int64) * p_b + cm_a) * (n_b + 2) \
-            + jnp.where(mp_before >= 0, mp_before, -2) + 2
-        h_cc = _mix32_jnp(jnp, st["seed"], wi, it, jnp.uint32(1))
-        h_n7 = _mix32_jnp(jnp, st["seed"], wi, it, jnp.uint32(0))
-        tenure = jnp.where(
-            cm_cc, p + h_cc.astype(jnp.int64) % (2 * p),
-            n + h_n7.astype(jnp.int64) % jnp.maximum(n, 1))
-        tabu_t = st["tabu"].at[
-            wi, jnp.where(found, destroyed,
-                          st["tabu"].shape[1])].set(
-            jnp.where(found, (it + tenure).astype(_I32), 0),
-            mode="drop")
+            # tabu the destroyed configuration (accepted moves only)
+            mp_before = take_w(mpred, cm_u[:, None])[:, 0]
+            destroyed = (cm_u.astype(jnp.int64) * p_b + cm_a) * (n_b + 2) \
+                + jnp.where(mp_before >= 0, mp_before, -2) + 2
+            h_cc = _mix32_jnp(jnp, st["seed"], wi, it, jnp.uint32(1))
+            h_n7 = _mix32_jnp(jnp, st["seed"], wi, it, jnp.uint32(0))
+            tenure = jnp.where(
+                cm_cc, p + h_cc.astype(jnp.int64) % (2 * p),
+                n + h_n7.astype(jnp.int64) % jnp.maximum(n, 1))
+            tabu_t = st["tabu"].at[
+                wi, jnp.where(found, destroyed,
+                              st["tabu"].shape[1])].set(
+                jnp.where(found, (it + tenure).astype(_I32), 0),
+                mode="drop")
 
-        # sequence splice (dst row gets remove+insert arithmetic; cc moves
-        # also rewrite the source row)
-        ii = jnp.arange(s_b)[None, :]
-        dst_row = jnp.take_along_axis(seq, cm_b[:, None, None], axis=1)[:, 0]
-        new_len_b = jnp.take_along_axis(seq_len, cm_b[:, None], axis=1)[:, 0] \
-            + cm_cc
-        t2 = ii - (ii > cm_j[:, None])
-        orig2 = t2 + ((~cm_cc)[:, None] & (t2 >= cm_k[:, None]))
-        g2 = jnp.take_along_axis(dst_row, jnp.clip(orig2, 0, s_b - 1), axis=1)
-        new_dst = jnp.where(ii == cm_j[:, None], cm_u[:, None], g2)
-        new_dst = jnp.where(ii < new_len_b[:, None], new_dst, -1).astype(_I32)
-        src_row = jnp.take_along_axis(seq, cm_a[:, None, None], axis=1)[:, 0]
-        src_len = jnp.take_along_axis(seq_len, cm_a[:, None], axis=1)[:, 0]
-        rem = jnp.take_along_axis(
-            src_row, jnp.clip(ii + (ii >= cm_k[:, None]), 0, s_b - 1), axis=1)
-        new_src = jnp.where(ii < (src_len - 1)[:, None], rem, -1).astype(_I32)
-        parange = jnp.arange(p_b)[None, :, None]
-        m_src = (parange == cm_a[:, None, None]) & (commit & cm_cc)[:, None, None]
-        m_dst = (parange == cm_b[:, None, None]) & commit[:, None, None]
-        seq_n = jnp.where(m_src, new_src[:, None, :], seq)
-        seq_n = jnp.where(m_dst, new_dst[:, None, :], seq_n)
-        parange2 = jnp.arange(p_b)[None, :]
-        seq_len_n = seq_len \
-            + ((parange2 == cm_b[:, None]) & commit[:, None]
-               & cm_cc[:, None]).astype(_I32) \
-            - ((parange2 == cm_a[:, None]) & commit[:, None]
-               & cm_cc[:, None]).astype(_I32)
-        assign_n = assign.at[
-            wi, jnp.where(commit, cm_u, n_b)].set(cm_b, mode="drop")
-        mp_n, ms_n = links_from_seq(seq_n, seq_len_n)
+            # sequence splice (dst row gets remove+insert arithmetic; cc moves
+            # also rewrite the source row)
+            ii = jnp.arange(s_b)[None, :]
+            dst_row = jnp.take_along_axis(seq, cm_b[:, None, None], axis=1)[:, 0]
+            new_len_b = jnp.take_along_axis(seq_len, cm_b[:, None], axis=1)[:, 0] \
+                + cm_cc
+            t2 = ii - (ii > cm_j[:, None])
+            orig2 = t2 + ((~cm_cc)[:, None] & (t2 >= cm_k[:, None]))
+            g2 = jnp.take_along_axis(dst_row, jnp.clip(orig2, 0, s_b - 1), axis=1)
+            new_dst = jnp.where(ii == cm_j[:, None], cm_u[:, None], g2)
+            new_dst = jnp.where(ii < new_len_b[:, None], new_dst, -1).astype(_I32)
+            src_row = jnp.take_along_axis(seq, cm_a[:, None, None], axis=1)[:, 0]
+            src_len = jnp.take_along_axis(seq_len, cm_a[:, None], axis=1)[:, 0]
+            rem = jnp.take_along_axis(
+                src_row, jnp.clip(ii + (ii >= cm_k[:, None]), 0, s_b - 1), axis=1)
+            new_src = jnp.where(ii < (src_len - 1)[:, None], rem, -1).astype(_I32)
+            parange = jnp.arange(p_b)[None, :, None]
+            m_src = (parange == cm_a[:, None, None]) & (commit & cm_cc)[:, None, None]
+            m_dst = (parange == cm_b[:, None, None]) & commit[:, None, None]
+            seq_n = jnp.where(m_src, new_src[:, None, :], seq)
+            seq_n = jnp.where(m_dst, new_dst[:, None, :], seq_n)
+            parange2 = jnp.arange(p_b)[None, :]
+            seq_len_n = seq_len \
+                + ((parange2 == cm_b[:, None]) & commit[:, None]
+                   & cm_cc[:, None]).astype(_I32) \
+                - ((parange2 == cm_a[:, None]) & commit[:, None]
+                   & cm_cc[:, None]).astype(_I32)
+            assign_n = assign.at[
+                wi, jnp.where(commit, cm_u, n_b)].set(cm_b, mode="drop")
+            mp_n, ms_n = links_from_seq(seq_n, seq_len_n)
 
-        start_n = jnp.where(commit[:, None], new_start, start)
-        finish_n = jnp.where(commit[:, None], new_finish, finish)
-        cur_mk_n = jnp.where(commit, new_mk, cur_mk)
-        accepted_n = st["accepted"] + found.astype(_I32)
+            start_n = jnp.where(commit[:, None], new_start, start)
+            finish_n = jnp.where(commit[:, None], new_finish, finish)
+            cur_mk_n = jnp.where(commit, new_mk, cur_mk)
+            accepted_n = st["accepted"] + found.astype(_I32)
 
-        improved = found & (cur_mk_n < best_mk - 1e-9)
-        best_mk_n = jnp.where(improved, cur_mk_n, best_mk)
-        unimp = jnp.where(
-            improved, 0,
-            st["unimproved"] + (participates & ~exhausted).astype(_I32))
-        active_n = active0 & (n_moves > 0) & (unimp < max_unimp)
+            improved = found & (cur_mk_n < best_mk - 1e-9)
+            best_mk_n = jnp.where(improved, cur_mk_n, best_mk)
+            unimp = jnp.where(
+                improved, 0,
+                st["unimproved"] + (participates & ~exhausted).astype(_I32))
+            active_n = active0 & (n_moves > 0) & (unimp < max_unimp)
 
-        st_out = dict(st)
-        st_out.update(
-            it=it, n_exact=n_exact, n_approx=n_approx, stop=stop,
-            n_perturb=st["n_perturb"] + perturb_w.sum(),
-            overflow=st["overflow"] | overflow,
-            seq=seq_n, seq_len=seq_len_n, assign=assign_n,
-            mpred=mp_n, msucc=ms_n,
-            start=start_n, finish=finish_n, cur_mk=cur_mk_n,
-            best_mk=best_mk_n, unimproved=unimp, accepted=accepted_n,
-            active=active_n, tabu=tabu_t,
-            best_seq=jnp.where(improved[:, None, None], seq_n, st["best_seq"]),
-            best_seq_len=jnp.where(improved[:, None], seq_len_n,
-                                   st["best_seq_len"]),
-            best_assign=jnp.where(improved[:, None], assign_n,
-                                  st["best_assign"]),
-            best_mem=jnp.where(improved[:, None], mem, st["best_mem"]),
-        )
+            st_out = dict(st)
+            st_out.update(
+                it=it, n_exact=n_exact, n_approx=n_approx, stop=stop,
+                n_perturb=st["n_perturb"] + perturb_w.sum(),
+                overflow=st["overflow"] | overflow,
+                seq=seq_n, seq_len=seq_len_n, assign=assign_n,
+                mpred=mp_n, msucc=ms_n,
+                start=start_n, finish=finish_n, cur_mk=cur_mk_n,
+                best_mk=best_mk_n, unimproved=unimp, accepted=accepted_n,
+                active=active_n, tabu=tabu_t,
+                best_seq=jnp.where(improved[:, None, None], seq_n, st["best_seq"]),
+                best_seq_len=jnp.where(improved[:, None], seq_len_n,
+                                       st["best_seq_len"]),
+                best_assign=jnp.where(improved[:, None], assign_n,
+                                      st["best_assign"]),
+                best_mem=jnp.where(improved[:, None], mem, st["best_mem"]),
+            )
         return st_out, overflow
 
     # ------------------------------------------------------------- run
@@ -881,8 +891,9 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
                                            series, r + R),
                                 advance, None)
 
-        st, series, _ = jax.lax.while_loop(
-            cond, body, (st, series, jnp.int64(0)))
+        with jax.named_scope("ts_round"):
+            st, series, _ = jax.lax.while_loop(
+                cond, body, (st, series, jnp.int64(0)))
         return st, series
 
     return run
@@ -1247,6 +1258,7 @@ def solve_instances(
     config: DeviceConfig | None = None,
     seeds: "list[int] | None" = None,
     callbacks: "list | None" = None,
+    cut: "int | None" = None,
 ) -> list[MultiWalkResult]:
     """Run the device engine over a batch of same-bucket instances in one
     vmapped compiled call per sync — an entire Table-II row per launch.
@@ -1273,9 +1285,16 @@ def solve_instances(
     return stops *that instance only* (its ``stop_reason`` becomes
     ``"callback"``).  This is the anytime-incumbent path the serve engine
     fans out to streaming clients.
+
+    The host work is recorded as profiler spans (``repro.search.prep``,
+    then per launch ``launch``, ``readback`` and ``sync``, and ``finish``),
+    each carrying ``cut`` as metadata where it is given: the serve engine
+    passes its cut's head request id.
     """
     import jax
+    import jax.numpy as jnp
     from jax import enable_x64
+    from jax.profiler import TraceAnnotation
 
     params = params or TSParams()
     cfg = config or DeviceConfig()
@@ -1296,211 +1315,215 @@ def solve_instances(
         raise ValueError("one callback slot per instance")
     t0 = time.monotonic()
 
-    cur_sols, scheds = [], []
-    for inst, init_list in zip(instances, inits):
-        sols = [memory_update(inst, s, refresh_every=params.mem_refresh_every,
-                              scalar=params.mem_update_scalar)
-                for s in init_list]
-        sc = [exact_schedule(inst, s) for s in sols]
-        if not all(x is not None for x in sc):
-            raise ValueError("initial solutions must be acyclic")
-        cur_sols.append(sols)
-        scheds.append(sc)
+    span = {} if cut is None else {"cut": cut}
+    with TraceAnnotation("repro.search.prep", **span):
+        cur_sols, scheds = [], []
+        for inst, init_list in zip(instances, inits):
+            sols = [memory_update(inst, s, refresh_every=params.mem_refresh_every,
+                                  scalar=params.mem_update_scalar)
+                    for s in init_list]
+            sc = [exact_schedule(inst, s) for s in sols]
+            if not all(x is not None for x in sc):
+                raise ValueError("initial solutions must be acyclic")
+            cur_sols.append(sols)
+            scheds.append(sc)
 
-    # shared buckets live on the batch: every padded axis is the max bucket
-    # across the batch, computed once at InstanceBatch construction
-    n_b = batch.n_b
-    packs = list(batch.packs)
-    crit_cap = cfg.crit_cap or max(
-        _auto_crit_cap(i, s, sc)
-        for i, s, sc in zip(instances, cur_sols, scheds))
+        # shared buckets live on the batch: every padded axis is the max bucket
+        # across the batch, computed once at InstanceBatch construction
+        n_b = batch.n_b
+        packs = list(batch.packs)
+        crit_cap = cfg.crit_cap or max(
+            _auto_crit_cap(i, s, sc)
+            for i, s, sc in zip(instances, cur_sols, scheds))
 
-    states = [pack_state(ip2, s, sc, sd)
-              for ip2, s, sc, sd in zip(packs, cur_sols, scheds, seeds)]
-    init_best = np.stack([st["best_mk"] for st in states])   # (I, W)
-    histories = [[[(0, float(init_best[i, w]))] for w in range(w_count)]
-                 for i in range(n_inst)]
-    g_hist = [[(0, float(init_best[i].min()))] for i in range(n_inst)]
-    g_best = [h[0][1] for h in g_hist]
-    mem_updates_on = params.mem_update_period < MEM_UPDATE_DISABLED
-    n_exact_host = np.zeros(n_inst, dtype=np.int64)
-    cb_stop = np.zeros(n_inst, dtype=bool)
-    timed_out = False
-    compile_s = 0.0
+        states = [pack_state(ip2, s, sc, sd)
+                  for ip2, s, sc, sd in zip(packs, cur_sols, scheds, seeds)]
+        init_best = np.stack([st["best_mk"] for st in states])   # (I, W)
+        histories = [[[(0, float(init_best[i, w]))] for w in range(w_count)]
+                     for i in range(n_inst)]
+        g_hist = [[(0, float(init_best[i].min()))] for i in range(n_inst)]
+        g_best = [h[0][1] for h in g_hist]
+        mem_updates_on = params.mem_update_period < MEM_UPDATE_DISABLED
+        n_exact_host = np.zeros(n_inst, dtype=np.int64)
+        cb_stop = np.zeros(n_inst, dtype=bool)
+        timed_out = False
+        compile_s = 0.0
 
-    state = {k: np.stack([st[k] for st in states]) for k in states[0]}
-    ia = batch.arrays()
+        state = {k: np.stack([st[k] for st in states]) for k in states[0]}
+        with enable_x64():
+            ia_j = {k: jnp.asarray(v) for k, v in batch.arrays().items()}
 
     with enable_x64():
-        import jax.numpy as jnp
-
-        ia_j = {k: jnp.asarray(v) for k, v in ia.items()}
         while True:
             if time.monotonic() - t0 > params.time_limit:
                 timed_out = True
                 break
-            tc = time.monotonic()
-            launch, fresh = _get_launch(packs[0], w_count, params, crit_cap,
-                                        cfg, batch=n_inst)
-            state_j = {k: jnp.asarray(v) for k, v in state.items()}
-            series0 = jax.vmap(
-                lambda _: _series_buffers(cfg.sync_every, w_count))(
-                jnp.arange(n_inst))
-            state_j, series = launch(ia_j, state_j, series0)
-            if fresh:
-                compile_s += time.monotonic() - tc
-            state = {k: np.array(v) for k, v in state_j.items()}  # writable
-            ser = {k: np.asarray(v) for k, v in series.items()}
+            with TraceAnnotation("repro.search.launch", **span):
+                tc = time.monotonic()
+                launch, fresh = _get_launch(packs[0], w_count, params, crit_cap,
+                                            cfg, batch=n_inst)
+                state_j = {k: jnp.asarray(v) for k, v in state.items()}
+                series0 = jax.vmap(
+                    lambda _: _series_buffers(cfg.sync_every, w_count))(
+                    jnp.arange(n_inst))
+                state_j, series = launch(ia_j, state_j, series0)
+                if fresh:
+                    compile_s += time.monotonic() - tc
+            with TraceAnnotation("repro.search.readback", **span):
+                state = {k: np.array(v) for k, v in state_j.items()}  # writable
+                ser = {k: np.asarray(v) for k, v in series.items()}
 
-            sync_improved = np.zeros(n_inst, dtype=bool)
-            for i in range(n_inst):
-                for r in range(cfg.sync_every):
-                    if not ser["ran"][i, r]:
-                        continue
-                    it_r = int(ser["it"][i, r])
-                    for w in range(w_count):
-                        bmk = float(ser["best_mk"][i, r, w])
-                        if bmk < histories[i][w][-1][1] - 1e-9:
-                            histories[i][w].append((it_r, bmk))
-                    nb = float(ser["best_mk"][i, r].min())
-                    if nb < g_best[i]:
-                        g_best[i] = nb
-                        g_hist[i].append((it_r, nb))
-                        sync_improved[i] = True
-
-            if state["overflow"].any():
-                state["overflow"][:] = False
-                crit_cap = min(max(crit_cap * 2, 32), n_b)
-                _note_overflow_relaunch()
-                continue
-
-            if callbacks is not None:
-                # per-instance anytime hooks, fired at the same boundary the
-                # single-instance driver uses (after overflow handling, before
-                # Alg-3); a truthy return retires only that instance
+            with TraceAnnotation("repro.search.sync", **span):
+                sync_improved = np.zeros(n_inst, dtype=bool)
                 for i in range(n_inst):
-                    cb = callbacks[i]
-                    if cb is None or cb_stop[i]:
-                        continue
-                    act = state["active"][i]
-                    if not act.any() and not sync_improved[i]:
-                        continue
-                    cur_min = float(state["cur_mk"][i][act].min()) \
-                        if act.any() else g_best[i]
-                    ev = TSEvent(
-                        iteration=int(state["it"][i]),
-                        best_makespan=g_best[i],
-                        current_makespan=cur_min,
-                        elapsed=time.monotonic() - t0,
-                        n_exact_evals=int(state["n_exact"][i])
-                        + int(n_exact_host[i]),
-                        n_approx_evals=int(state["n_approx"][i]),
-                        improved=bool(sync_improved[i]))
-                    on_imp = getattr(cb, "on_improvement", None)
-                    if sync_improved[i] and on_imp is not None and on_imp(ev):
-                        cb_stop[i] = True
-                    on_it = getattr(cb, "on_iteration", None)
-                    if not cb_stop[i] and on_it is not None and on_it(ev):
-                        cb_stop[i] = True
-                    if cb_stop[i]:
-                        state["active"][i, :] = False
-
-            done = ~state["active"].any(axis=1) | state["stop"]
-            if params.max_iters is not None:
-                done |= state["it"] >= params.max_iters
-            if params.max_evals is not None:
-                done |= state["n_exact"] >= params.max_evals
-            if done.all():
-                break
-
-            if mem_updates_on:
-                for i in range(n_inst):
-                    if done[i]:
-                        continue
-                    sub = {k: state[k][i] for k in state}
-                    for w in range(w_count):
-                        if not sub["active"][w]:
+                    for r in range(cfg.sync_every):
+                        if not ser["ran"][i, r]:
                             continue
-                        sol_w = unpack_solution(packs[i], sub["seq"],
-                                                sub["seq_len"], sub["assign"],
-                                                sub["mem"], w)
-                        sol_w = memory_update(
-                            instances[i], sol_w,
-                            refresh_every=params.mem_refresh_every,
-                            scalar=params.mem_update_scalar)
-                        sched_w = exact_schedule(instances[i], sol_w)
-                        if sched_w is None:
-                            raise RuntimeError(
-                                "memory_update returned a cyclic solution")
-                        n_exact_host[i] += 1
-                        _write_walk(packs[i], sub, w, sol_w, sched_w)
-                        if sched_w.makespan < sub["best_mk"][w] - 1e-9:
-                            sub["best_mk"][w] = sched_w.makespan
-                            sub["best_seq"][w] = sub["seq"][w]
-                            sub["best_seq_len"][w] = sub["seq_len"][w]
-                            sub["best_assign"][w] = sub["assign"][w]
-                            sub["best_mem"][w] = sub["mem"][w]
-                            it_now = int(sub["it"])
-                            histories[i][w].append(
-                                (it_now, float(sched_w.makespan)))
-                            if sched_w.makespan < g_best[i]:
-                                g_best[i] = float(sched_w.makespan)
-                                g_hist[i].append((it_now, g_best[i]))
-                    for k in state:
-                        state[k][i] = sub[k]
+                        it_r = int(ser["it"][i, r])
+                        for w in range(w_count):
+                            bmk = float(ser["best_mk"][i, r, w])
+                            if bmk < histories[i][w][-1][1] - 1e-9:
+                                histories[i][w].append((it_r, bmk))
+                        nb = float(ser["best_mk"][i, r].min())
+                        if nb < g_best[i]:
+                            g_best[i] = nb
+                            g_hist[i].append((it_r, nb))
+                            sync_improved[i] = True
+
+                if state["overflow"].any():
+                    state["overflow"][:] = False
+                    crit_cap = min(max(crit_cap * 2, 32), n_b)
+                    _note_overflow_relaunch()
+                    continue
+
+                if callbacks is not None:
+                    # per-instance anytime hooks, fired at the same boundary the
+                    # single-instance driver uses (after overflow handling, before
+                    # Alg-3); a truthy return retires only that instance
+                    for i in range(n_inst):
+                        cb = callbacks[i]
+                        if cb is None or cb_stop[i]:
+                            continue
+                        act = state["active"][i]
+                        if not act.any() and not sync_improved[i]:
+                            continue
+                        cur_min = float(state["cur_mk"][i][act].min()) \
+                            if act.any() else g_best[i]
+                        ev = TSEvent(
+                            iteration=int(state["it"][i]),
+                            best_makespan=g_best[i],
+                            current_makespan=cur_min,
+                            elapsed=time.monotonic() - t0,
+                            n_exact_evals=int(state["n_exact"][i])
+                            + int(n_exact_host[i]),
+                            n_approx_evals=int(state["n_approx"][i]),
+                            improved=bool(sync_improved[i]))
+                        on_imp = getattr(cb, "on_improvement", None)
+                        if sync_improved[i] and on_imp is not None and on_imp(ev):
+                            cb_stop[i] = True
+                        on_it = getattr(cb, "on_iteration", None)
+                        if not cb_stop[i] and on_it is not None and on_it(ev):
+                            cb_stop[i] = True
+                        if cb_stop[i]:
+                            state["active"][i, :] = False
+
+                done = ~state["active"].any(axis=1) | state["stop"]
+                if params.max_iters is not None:
+                    done |= state["it"] >= params.max_iters
+                if params.max_evals is not None:
+                    done |= state["n_exact"] >= params.max_evals
+                if done.all():
+                    break
+
+                if mem_updates_on:
+                    for i in range(n_inst):
+                        if done[i]:
+                            continue
+                        sub = {k: state[k][i] for k in state}
+                        for w in range(w_count):
+                            if not sub["active"][w]:
+                                continue
+                            sol_w = unpack_solution(packs[i], sub["seq"],
+                                                    sub["seq_len"], sub["assign"],
+                                                    sub["mem"], w)
+                            sol_w = memory_update(
+                                instances[i], sol_w,
+                                refresh_every=params.mem_refresh_every,
+                                scalar=params.mem_update_scalar)
+                            sched_w = exact_schedule(instances[i], sol_w)
+                            if sched_w is None:
+                                raise RuntimeError(
+                                    "memory_update returned a cyclic solution")
+                            n_exact_host[i] += 1
+                            _write_walk(packs[i], sub, w, sol_w, sched_w)
+                            if sched_w.makespan < sub["best_mk"][w] - 1e-9:
+                                sub["best_mk"][w] = sched_w.makespan
+                                sub["best_seq"][w] = sub["seq"][w]
+                                sub["best_seq_len"][w] = sub["seq_len"][w]
+                                sub["best_assign"][w] = sub["assign"][w]
+                                sub["best_mem"][w] = sub["mem"][w]
+                                it_now = int(sub["it"])
+                                histories[i][w].append(
+                                    (it_now, float(sched_w.makespan)))
+                                if sched_w.makespan < g_best[i]:
+                                    g_best[i] = float(sched_w.makespan)
+                                    g_hist[i].append((it_now, g_best[i]))
+                        for k in state:
+                            state[k][i] = sub[k]
 
     results = []
-    for i in range(n_inst):
-        active = state["active"][i]
-        if cb_stop[i]:
-            stop_reason = "callback"
-        elif not active.any():
-            stop_reason = "converged"
-        elif timed_out:
-            stop_reason = "time_limit"
-        elif params.max_iters is not None and \
-                state["it"][i] >= params.max_iters:
-            stop_reason = "max_iters"
-        elif state["stop"][i] or (params.max_evals is not None and
-                                  state["n_exact"][i] >= params.max_evals):
-            stop_reason = "max_evals"
-        else:
-            stop_reason = "time_limit"
-        best_mk = np.array(state["best_mk"][i])
-        best_sols = [
-            unpack_solution(packs[i], state["best_seq"][i],
-                            state["best_seq_len"][i], state["best_assign"][i],
-                            state["best_mem"][i], w)
-            for w in range(w_count)
-        ]
-        if mem_updates_on:
-            best_sols, best_mk = _repair_bests(instances[i], params,
-                                               best_sols, best_mk)
-        gi = int(np.argmin(best_mk))
-        _maybe_sanitize(instances[i], best_sols[gi],
-                        f"solve_instances final best (instance {i})",
-                        params, mk=float(best_mk[gi]),
-                        capacity=mem_updates_on)
-        per_walk = [
-            WalkInfo(init_label=f"walk{w}",
-                     initial_makespan=histories[i][w][0][1],
-                     best_makespan=float(best_mk[w]), best=best_sols[w],
-                     history=histories[i][w],
-                     stop_reason=stop_reason if active[w] else "converged")
-            for w in range(w_count)
-        ]
-        res = MultiWalkResult(
-            best=best_sols[gi], best_makespan=float(best_mk[gi]),
-            initial_makespan=float(init_best[i].min()),
-            iterations=int(state["it"][i]),
-            elapsed=time.monotonic() - t0,
-            history=g_hist[i],
-            n_exact_evals=int(state["n_exact"][i]) + int(n_exact_host[i]),
-            n_approx_evals=int(state["n_approx"][i]),
-            stop_reason=stop_reason, walks=w_count, per_walk=per_walk,
-        )
-        res.compile_seconds = compile_s  # type: ignore[attr-defined]
-        results.append(res)
+    with TraceAnnotation("repro.search.finish", **span):
+        for i in range(n_inst):
+            active = state["active"][i]
+            if cb_stop[i]:
+                stop_reason = "callback"
+            elif not active.any():
+                stop_reason = "converged"
+            elif timed_out:
+                stop_reason = "time_limit"
+            elif params.max_iters is not None and \
+                    state["it"][i] >= params.max_iters:
+                stop_reason = "max_iters"
+            elif state["stop"][i] or (params.max_evals is not None and
+                                      state["n_exact"][i] >= params.max_evals):
+                stop_reason = "max_evals"
+            else:
+                stop_reason = "time_limit"
+            best_mk = np.array(state["best_mk"][i])
+            best_sols = [
+                unpack_solution(packs[i], state["best_seq"][i],
+                                state["best_seq_len"][i], state["best_assign"][i],
+                                state["best_mem"][i], w)
+                for w in range(w_count)
+            ]
+            if mem_updates_on:
+                best_sols, best_mk = _repair_bests(instances[i], params,
+                                                   best_sols, best_mk)
+            gi = int(np.argmin(best_mk))
+            _maybe_sanitize(instances[i], best_sols[gi],
+                            f"solve_instances final best (instance {i})",
+                            params, mk=float(best_mk[gi]),
+                            capacity=mem_updates_on)
+            per_walk = [
+                WalkInfo(init_label=f"walk{w}",
+                         initial_makespan=histories[i][w][0][1],
+                         best_makespan=float(best_mk[w]), best=best_sols[w],
+                         history=histories[i][w],
+                         stop_reason=stop_reason if active[w] else "converged")
+                for w in range(w_count)
+            ]
+            res = MultiWalkResult(
+                best=best_sols[gi], best_makespan=float(best_mk[gi]),
+                initial_makespan=float(init_best[i].min()),
+                iterations=int(state["it"][i]),
+                elapsed=time.monotonic() - t0,
+                history=g_hist[i],
+                n_exact_evals=int(state["n_exact"][i]) + int(n_exact_host[i]),
+                n_approx_evals=int(state["n_approx"][i]),
+                stop_reason=stop_reason, walks=w_count, per_walk=per_walk,
+            )
+            res.compile_seconds = compile_s  # type: ignore[attr-defined]
+            results.append(res)
     return results
 
 

@@ -18,12 +18,19 @@ compiled programs per signature covers every cut width.  ``warmup``
 pre-compiles those programs from declared :class:`WarmSpec` traffic
 classes via ``device_search.warm_launches`` — backed by the launch LRU
 and JAX's persistent cache (placed by ``serve.compile_cache``).
+
+The per-cut host work is recorded as profiler spans (``repro.engine.*``,
+``jax.profiler.TraceAnnotation``), each carrying the cut's head request id
+as ``cut`` and, where it is one request's work, that request's ``rid``.
+They cost a construction each and record only inside a profiler session.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
 import time
+
+from jax.profiler import TraceAnnotation
 
 from ..core.api import (
     Budget,
@@ -83,8 +90,8 @@ class WarmSpec:
 @dataclasses.dataclass
 class RequestResult:
     """What the service hands back per request: the solo-identical report
-    plus serving metrics (queue wait, batch shape, cut reason, cache
-    deltas; the service adds end-to-end ``latency``)."""
+    plus serving metrics (queue wait, batch shape, cut reason, assembly and
+    solve seconds; the service adds end-to-end ``latency``)."""
 
     request: SolveRequest
     report: SolveReport
@@ -210,34 +217,42 @@ class Engine:
         t0 = time.monotonic()
         backend = backend or self.config.backend
         reqs = cut.requests
+        head = reqs[0].rid
         walks = reqs[0].walks
         ts = _budgeted_ts_params(self.params, reqs[0].budget, reqs[0].seed)
         good: "list[SolveRequest]" = []
         failures: "list[RequestFailure]" = []
         instances, seeds, inits = [], [], []
-        for r in reqs:
-            try:
-                ini = multiwalk_inits(r.instance, walks, r.seed)[0]
-            except Exception as e:
-                # typed per-lane attribution (wrap_error → InfeasibleRequest
-                # etc.); siblings keep assembling — DESIGN §13 blast radius
-                failures.append(RequestFailure(r, wrap_error(e, rid=r.rid)))
-                continue
-            good.append(r)
-            instances.append(r.instance)
-            seeds.append(r.seed)
-            inits.append(ini)
-        batch = None
-        padded_to = len(good)
-        if backend == "device" and good:
-            padded_to = self._quantized_size(len(good))
-            while len(instances) < padded_to:
-                # pad lanes repeat the last request; vmap batch identity
-                # keeps them from touching real lanes, and fan-out drops them
-                instances.append(good[-1].instance)
-                inits.append([s.copy() for s in inits[len(good) - 1]])
-                seeds.append(good[-1].seed)
-            batch = self._make_batch(instances, cut.signature)
+        with TraceAnnotation("repro.engine.assemble", cut=head):
+            for r in reqs:
+                try:
+                    with TraceAnnotation("repro.engine.inits", cut=head,
+                                         rid=r.rid):
+                        ini = multiwalk_inits(r.instance, walks, r.seed)[0]
+                except Exception as e:
+                    # typed per-lane attribution (wrap_error →
+                    # InfeasibleRequest etc.); siblings keep assembling —
+                    # DESIGN §13 blast radius
+                    failures.append(RequestFailure(r, wrap_error(e,
+                                                                 rid=r.rid)))
+                    continue
+                good.append(r)
+                instances.append(r.instance)
+                seeds.append(r.seed)
+                inits.append(ini)
+            batch = None
+            padded_to = len(good)
+            if backend == "device" and good:
+                padded_to = self._quantized_size(len(good))
+                while len(instances) < padded_to:
+                    # pad lanes repeat the last request; vmap batch identity
+                    # keeps them from touching real lanes, and fan-out drops
+                    # them
+                    instances.append(good[-1].instance)
+                    inits.append([s.copy() for s in inits[len(good) - 1]])
+                    seeds.append(good[-1].seed)
+                with TraceAnnotation("repro.engine.pack", cut=head):
+                    batch = self._make_batch(instances, cut.signature)
         return AssembledBatch(cut=cut, instances=instances, inits=inits,
                               seeds=seeds, params=ts, batch=batch,
                               padded_to=padded_to,
@@ -264,54 +279,51 @@ class Engine:
         if not reqs:
             self.n_batches += 1
             return results
-        # chaos harness: a whole-launch fault is attributable only when the
-        # cut has a single lane (key the decision on the head rid so the
-        # schedule is stable under re-dispatch)
-        _inject.fire("engine.execute.launch", key=reqs[0].rid,
-                     rid=reqs[0].rid if len(reqs) == 1 else None)
-        if backend == "device":
-            from ..core.device_search import (
-                DeviceConfig,
-                launch_cache_info,
-                solve_instances,
-            )
+        head = cut.requests[0].rid
+        with TraceAnnotation("repro.engine.execute", cut=head):
+            # chaos harness: a whole-launch fault is attributable only when
+            # the cut has a single lane (key the decision on the head rid so
+            # the schedule is stable under re-dispatch)
+            _inject.fire("engine.execute.launch", key=reqs[0].rid,
+                         rid=reqs[0].rid if len(reqs) == 1 else None)
+            if backend == "device":
+                from ..core.device_search import DeviceConfig, solve_instances
 
-            cache0 = launch_cache_info()
-            cap = self.config.crit_cap or assembled.batch.n_b
-            cbs = None
-            if callbacks is not None:
-                cbs = [cb_by_rid.get(r.rid) for r in reqs] + \
-                    [None] * (assembled.padded_to - len(reqs))
-            rs = solve_instances(
-                assembled.batch, assembled.inits, assembled.params,
-                config=DeviceConfig(sync_every=self.config.sync_every,
-                                    crit_cap=cap),
-                seeds=assembled.seeds, callbacks=cbs)
-            wall = time.monotonic() - t0
-            cache1 = launch_cache_info()
-            delta = {k: cache1[k] - cache0[k]
-                     for k in ("hits", "misses", "evictions",
-                               "overflow_relaunches")}
-            for i, r in enumerate(reqs):  # pad lanes i >= len(reqs) dropped
-                rep = _report_from_multiwalk("tabu_device", r.instance,
-                                             rs[i], "device", wall)
-                results.append(self._lane_result(r, rep, assembled, wall,
-                                                 delta))
-        else:
-            for r in reqs:
-                cb = cb_by_rid.get(r.rid) or Callbacks()
-                try:
-                    rep = solve(r.instance, "tabu_multiwalk", walks=r.walks,
-                                budget=r.budget, seed=r.seed, callbacks=cb,
-                                params=self.params)
-                except Exception as e:
-                    # per-lane attribution: this request fails typed
-                    # (wrap_error), its siblings still get their results
-                    results.append(RequestFailure(r, wrap_error(e,
-                                                                rid=r.rid)))
-                    continue
-                results.append(self._lane_result(r, rep, assembled,
-                                                 time.monotonic() - t0, {}))
+                cap = self.config.crit_cap or assembled.batch.n_b
+                cbs = None
+                if callbacks is not None:
+                    cbs = [cb_by_rid.get(r.rid) for r in reqs] + \
+                        [None] * (assembled.padded_to - len(reqs))
+                rs = solve_instances(
+                    assembled.batch, assembled.inits, assembled.params,
+                    config=DeviceConfig(sync_every=self.config.sync_every,
+                                        crit_cap=cap),
+                    seeds=assembled.seeds, callbacks=cbs, cut=head)
+                wall = time.monotonic() - t0
+                # pad lanes i >= len(reqs) are dropped
+                for i, r in enumerate(reqs):
+                    with TraceAnnotation("repro.engine.fanout", cut=head,
+                                         rid=r.rid):
+                        rep = _report_from_multiwalk(
+                            "tabu_device", r.instance, rs[i], "device", wall)
+                        results.append(self._lane_result(r, rep, assembled,
+                                                         wall))
+            else:
+                for r in reqs:
+                    cb = cb_by_rid.get(r.rid) or Callbacks()
+                    try:
+                        rep = solve(r.instance, "tabu_multiwalk",
+                                    walks=r.walks, budget=r.budget,
+                                    seed=r.seed, callbacks=cb,
+                                    params=self.params)
+                    except Exception as e:
+                        # per-lane attribution: this request fails typed
+                        # (wrap_error), its siblings still get their results
+                        results.append(RequestFailure(
+                            r, wrap_error(e, rid=r.rid)))
+                        continue
+                    results.append(self._lane_result(r, rep, assembled,
+                                                     time.monotonic() - t0))
         self.n_batches += 1
         self.n_requests += len(reqs)
         return results
@@ -322,16 +334,16 @@ class Engine:
         return os.environ.get("REPRO_SANITIZE", "").strip().lower() not in (
             "", "0", "false", "no", "off")
 
-    def _lane_result(self, req, report, assembled, wall, cache_delta):
+    def _lane_result(self, req, report, assembled, wall):
         """Build one lane's result, converting a certification failure into
         that lane's typed :class:`RequestFailure` (CertifyFailure carrying
         the sanitizer's certificate as ``__cause__``)."""
         try:
-            return self._result(req, report, assembled, wall, cache_delta)
+            return self._result(req, report, assembled, wall)
         except Exception as e:
             return RequestFailure(req, wrap_error(e, rid=req.rid))
 
-    def _result(self, req, report, assembled, wall, cache_delta):
+    def _result(self, req, report, assembled, wall):
         cut = assembled.cut
         # chaos harness: corrupt the served incumbent / NaN the reported
         # makespan *before* certification, so sanitize mode must catch it
@@ -370,5 +382,4 @@ class Engine:
             "queue_wait": cut.cut_at - req.submitted,
             "assemble_seconds": assembled.assemble_seconds,
             "solve_seconds": wall,
-            "launch_cache": dict(cache_delta),
         })

@@ -21,6 +21,11 @@ signatures, admission control sheds with ``QueueOverload`` when the queue
 is at depth, a watchdog abandons launches exceeding their deadline, and
 an unattributable batch failure re-dispatches lanes in isolation instead
 of failing the cut wholesale.
+
+Profiler spans mark ``submit`` on the event loop (``repro.serve.submit``,
+with the request's ``rid``) and the dispatch thread's wait for the device
+lane with an assembled cut in hand (``repro.serve.lane_wait``, with the
+cut's head request id as ``cut``); the engine records the rest.
 """
 from __future__ import annotations
 
@@ -29,6 +34,8 @@ import concurrent.futures
 import dataclasses
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from ..core.tabu import TSParams
 from ..faults import inject as _inject
@@ -175,28 +182,30 @@ class SolveService:
         may shed with :class:`~repro.faults.errors.QueueOverload` (carrying
         ``retry_after``) when the queue is at depth or the deadline is
         already unmeetable."""
-        req = self.queue.make_request(instance, budget, seed=seed,
-                                      walks=walks, deadline=deadline)
-        shed = self.resilience.admit(depth=len(self.queue),
-                                     now=self.queue.clock(),
-                                     deadline=req.deadline)
-        if shed is not None:
-            shed.rid = req.rid
-            raise shed
-        fut = self._loop.create_future()
-        with self._lock:
-            self._futures[req.rid] = fut
-            self._streams[req.rid] = asyncio.Queue()
-            self._stream_cbs[req.rid] = _StreamCallback(self._post_event,
-                                                        req.rid)
-        try:
-            self.queue.put(req)
-        except ServiceClosed:
+        with TraceAnnotation("repro.serve.submit") as span:
+            req = self.queue.make_request(instance, budget, seed=seed,
+                                          walks=walks, deadline=deadline)
+            span.set_metadata(rid=req.rid)
+            shed = self.resilience.admit(depth=len(self.queue),
+                                         now=self.queue.clock(),
+                                         deadline=req.deadline)
+            if shed is not None:
+                shed.rid = req.rid
+                raise shed
+            fut = self._loop.create_future()
             with self._lock:
-                self._futures.pop(req.rid, None)
-                self._streams.pop(req.rid, None)
-                self._stream_cbs.pop(req.rid, None)
-            raise
+                self._futures[req.rid] = fut
+                self._streams[req.rid] = asyncio.Queue()
+                self._stream_cbs[req.rid] = _StreamCallback(self._post_event,
+                                                            req.rid)
+            try:
+                self.queue.put(req)
+            except ServiceClosed:
+                with self._lock:
+                    self._futures.pop(req.rid, None)
+                    self._streams.pop(req.rid, None)
+                    self._stream_cbs.pop(req.rid, None)
+                raise
         return req.rid
 
     async def result(self, rid: int) -> RequestResult:
@@ -284,8 +293,12 @@ class SolveService:
                     with self._lock:
                         cbs = [self._stream_cbs.get(r.rid)
                                for r in cut.requests]
-                    while inflight is not None:  # wait for the device lane
-                        inflight = self._poll_inflight(inflight, block=True)
+                    if inflight is not None:  # wait for the device lane
+                        with TraceAnnotation("repro.serve.lane_wait",
+                                             cut=cut.requests[0].rid):
+                            while inflight is not None:
+                                inflight = self._poll_inflight(inflight,
+                                                               block=True)
                     inflight = (self._pool.submit(self.engine.execute,
                                                   assembled, cbs),
                                 cut, self._clock())
