@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from .greedy import GreedyState, _close_consumed_blocks, _peak_with, _try_alloc_outputs
+from .greedy import GreedyState, _candidate_ends, _commit_task, _place_initial_input
 from .mdfg import Instance
 from .solution import Solution
 
@@ -22,24 +22,11 @@ def load_balance(inst: Instance, rng: np.random.Generator | int = 0) -> Solution
     assign = np.full(n, -1, dtype=np.int64)
     mem = np.full(inst.n_data, -1, dtype=np.int64)
     proc_seq: list[list[int]] = [[] for _ in range(inst.n_procs)]
-    state = GreedyState(
-        finish=np.full(n, np.nan),
-        start=np.full(n, np.nan),
-        core_free=np.zeros(inst.n_procs),
-        intervals=[[] for _ in range(inst.n_mems)],
-        interval_of_block={},
-    )
+    state = GreedyState.empty(inst)
     for d in np.nonzero(inst.producer < 0)[0]:
-        for m in np.argsort(inst.mem_level):
-            if not inst.data_mem_ok[d, m]:
-                continue
-            if np.isinf(inst.mem_cap[m]) or _peak_with(
-                state.intervals[m], 0.0, inst.data_size[d]
-            ) <= inst.mem_cap[m]:
-                mem[d] = m
-                state.intervals[m].append([0.0, np.inf, float(inst.data_size[d])])
-                state.interval_of_block[int(d)] = (int(m), len(state.intervals[m]) - 1)
-                break
+        m = _place_initial_input(inst, state, int(d))
+        if m is not None:
+            mem[d] = m
 
     n_preds = np.diff(inst.pred_indptr)
     n_sched = np.zeros(n, dtype=np.int64)
@@ -58,25 +45,12 @@ def load_balance(inst: Instance, rng: np.random.Generator | int = 0) -> Solution
         # most idle compatible core (earliest free; ties → least busy)
         procs = inst.compatible_procs(t)
         c = int(min(procs, key=lambda p: (state.core_free[p], len(proc_seq[p]))))
-        st = max(ready, state.core_free[c])
-        out_choice = _try_alloc_outputs(inst, state, t, st, slack, commit=False)
-        t_in = sum(
-            inst.data_size[d] * inst.access_time[c, mem[d] if mem[d] >= 0 else inst.n_mems - 1]
-            for d in inst.inputs(t)
-        )
-        t_out = sum(inst.data_size[d] * inst.access_time[c, m] for d, m in out_choice.items())
-        end = st + t_in + inst.proc_time[t, c] + t_out
+        ends, starts, outs, choice = _candidate_ends(
+            inst, state, mem, t, np.array([c]), ready, slack)
 
         assign[t] = c
         proc_seq[c].append(t)
-        state.start[t] = st
-        state.finish[t] = end
-        state.core_free[c] = end
-        for d, m in out_choice.items():
-            mem[d] = m
-            state.intervals[m].append([st, np.inf, float(inst.data_size[d])])
-            state.interval_of_block[d] = (m, len(state.intervals[m]) - 1)
-        _close_consumed_blocks(inst, state, t, end)
+        _commit_task(inst, state, mem, t, c, starts[0], ends[0], outs, choice[0])
         remaining.discard(t)
         frontier.discard(t)
         for v in inst.succs(t):
