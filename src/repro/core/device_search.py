@@ -54,7 +54,9 @@ identical to per-instance runs because every loop update is masked.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
 import time
 
 import numpy as np
@@ -68,6 +70,7 @@ from .tabu import MultiWalkResult, TSEvent, TSParams, WalkInfo, _maybe_sanitize
 __all__ = [
     "DeviceConfig",
     "MEM_UPDATE_DISABLED",
+    "REPAIRS",
     "device_multiwalk",
     "solve_instances",
     "warm_launches",
@@ -115,6 +118,14 @@ def launch_cache_info() -> dict:
 def _note_overflow_relaunch() -> None:
     global _OVERFLOW_RELAUNCHES
     _OVERFLOW_RELAUNCHES += 1
+
+
+REPAIRS: "collections.Counter[str]" = collections.Counter()
+"""Walk bests served with Algorithm 3 on (``walks``); of them, those whose
+device best was over capacity (``infeasible``); and of those, the walks
+served from their best feasible schedule because the repaired best came out
+worse (``fallback``)."""
+_REPAIRS_LOCK = threading.Lock()   # the serve engine solves on several threads
 
 
 # --------------------------------------------------------------------------- #
@@ -1004,6 +1015,7 @@ def device_multiwalk(
         _ckpt.check_compatible(resume_from, instance_fp=ckpt_fp[0],
                                params_fp=ckpt_fp[1], walks=w_count)
         state = {k: np.array(v) for k, v in resume_from.state.items()}
+        feasible = {k: np.array(v) for k, v in resume_from.feasible.items()}
         crit_cap = int(resume_from.crit_cap)
         histories = [list(h) for h in resume_from.histories]
         g_best = float(resume_from.g_best)
@@ -1013,15 +1025,13 @@ def device_multiwalk(
         sync_index = int(resume_from.sync_index)
         t0 -= float(resume_from.elapsed)  # time budget carries over
     else:
-        cur_sols = [memory_update(inst, init,
-                                  refresh_every=params.mem_refresh_every,
-                                  scalar=params.mem_update_scalar)
-                    for init in inits]
+        cur_sols = [_alg3(inst, init, params, {}) for init in inits]
         scheds = [exact_schedule(inst, s) for s in cur_sols]
         if not all(s is not None for s in scheds):
             raise ValueError("initial solutions must be acyclic")
 
         state = pack_state(ip, cur_sols, scheds, params.seed)
+        feasible = _feasible_record(state)
         crit_cap = cfg.crit_cap or _auto_crit_cap(inst, cur_sols, scheds)
 
         best_mk0 = state["best_mk"].copy()
@@ -1041,7 +1051,7 @@ def device_multiwalk(
             sync_index=sync_index, crit_cap=crit_cap,
             elapsed=time.monotonic() - t0, n_exact_host=n_exact_host,
             g_best=g_best, init_mk_min=init_mk_min, g_hist=g_hist,
-            histories=histories, state=state)
+            histories=histories, state=state, feasible=feasible)
 
     def _fire(cb, improved: bool, it: int, cur_min: float) -> bool:
         if cb is None:
@@ -1127,14 +1137,13 @@ def device_multiwalk(
                         continue
                     sol_w = unpack_solution(ip, state["seq"], state["seq_len"],
                                             state["assign"], state["mem"], w)
-                    sol_w = memory_update(
-                        inst, sol_w, refresh_every=params.mem_refresh_every,
-                        scalar=params.mem_update_scalar)
+                    sol_w = _alg3(inst, sol_w, params, {})
                     sched_w = exact_schedule(inst, sol_w)
                     if sched_w is None:
                         raise RuntimeError("memory_update returned a cyclic solution")
                     n_exact_host += 1
                     _write_walk(ip, state, w, sol_w, sched_w)
+                    _note_feasible(feasible, state, w)
                     if sched_w.makespan < state["best_mk"][w] - 1e-9:
                         state["best_mk"][w] = sched_w.makespan
                         state["best_seq"][w] = state["seq"][w]
@@ -1164,10 +1173,10 @@ def device_multiwalk(
     ]
     best_mk = np.array(state["best_mk"])
     if mem_updates_on:
-        # in-launch incumbents were taken with a frozen allocation; re-run
-        # Alg-3 on any capacity-infeasible walk best so the report upholds
-        # the legacy drivers' feasibility contract
-        best_sols, best_mk = _repair_bests(inst, params, best_sols, best_mk)
+        # in-launch incumbents were taken with a frozen allocation; the
+        # report upholds the legacy drivers' feasibility contract
+        best_sols, best_mk = _repair_bests(inst, params, ip, best_sols,
+                                           best_mk, feasible, {})
     gi = int(np.argmin(best_mk))
     _maybe_sanitize(inst, best_sols[gi], "device_multiwalk final best",
                     params, mk=float(best_mk[gi]), capacity=mem_updates_on)
@@ -1196,22 +1205,66 @@ def device_multiwalk(
     return res
 
 
-def _repair_bests(inst: Instance, params: TSParams, best_sols, best_mk):
-    """Re-run Algorithm 3 on capacity-infeasible walk incumbents (their
-    allocation was frozen between syncs) and refresh their makespans."""
+def _alg3(inst: Instance, sol: Solution, params: TSParams, span: dict) -> Solution:
+    """Algorithm 3 at the search's settings, recorded as the host span
+    ``repro.search.alg3`` (``span``: its metadata, the cut's id)."""
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation("repro.search.alg3", **span):
+        return memory_update(inst, sol, refresh_every=params.mem_refresh_every,
+                             scalar=params.mem_update_scalar)
+
+
+_FEASIBLE_ROWS = ("seq", "seq_len", "assign", "mem")
+
+
+def _feasible_record(state: dict) -> dict:
+    """Each walk's best capacity-feasible schedule seen on the host: copies
+    of its packed rows and its exact makespan (``mk``), seeded from the
+    walks of ``state`` (their starts after Algorithm 3)."""
+    rec = {k: np.array(state[k]) for k in _FEASIBLE_ROWS}
+    rec["mk"] = np.array(state["cur_mk"], dtype=np.float64)
+    return rec
+
+
+def _note_feasible(rec: dict, state: dict, w: int) -> None:
+    """Keep walk ``w``'s rows of ``state``, just written after Algorithm 3
+    with their exact makespan in ``cur_mk``, where they beat its record."""
+    if state["cur_mk"][w] < rec["mk"][w]:
+        for k in _FEASIBLE_ROWS:
+            rec[k][w] = state[k][w]
+        rec["mk"][w] = state["cur_mk"][w]
+
+
+def _repair_bests(inst: Instance, params: TSParams, ip: InstancePack,
+                  best_sols, best_mk, feasible: dict, span: dict):
+    """Serve every walk a capacity-feasible best.  A device best was taken
+    under the allocation frozen since the last sync and may be over
+    capacity: Algorithm 3 re-runs on it, and where the repaired schedule
+    comes out worse than the walk's best feasible one (``feasible``, from
+    :func:`_feasible_record`), that one is served.  Ties go to the repair.
+    Counted in ``REPAIRS``."""
     from .solution import memory_feasible
 
+    tally = collections.Counter(walks=len(best_sols))
     for w, sol in enumerate(best_sols):
         sched = exact_schedule(inst, sol)
         assert sched is not None
         if memory_feasible(inst, sol, sched):
             continue
-        sol = memory_update(inst, sol, refresh_every=params.mem_refresh_every,
-                            scalar=params.mem_update_scalar)
+        tally["infeasible"] += 1
+        sol = _alg3(inst, sol, params, span)
         sched = exact_schedule(inst, sol)
         assert sched is not None
-        best_sols[w] = sol
         best_mk[w] = sched.makespan
+        if sched.makespan > feasible["mk"][w]:
+            tally["fallback"] += 1
+            sol = unpack_solution(ip, feasible["seq"], feasible["seq_len"],
+                                  feasible["assign"], feasible["mem"], w)
+            best_mk[w] = feasible["mk"][w]
+        best_sols[w] = sol
+    with _REPAIRS_LOCK:
+        REPAIRS.update(tally)
     return best_sols, best_mk
 
 
@@ -1287,9 +1340,10 @@ def solve_instances(
     fans out to streaming clients.
 
     The host work is recorded as profiler spans (``repro.search.prep``,
-    then per launch ``launch``, ``readback`` and ``sync``, and ``finish``),
-    each carrying ``cut`` as metadata where it is given: the serve engine
-    passes its cut's head request id.
+    then per launch ``launch``, ``readback`` and ``sync``, and ``finish``,
+    with ``repro.search.alg3`` around each Algorithm 3 inside them), each
+    carrying ``cut`` as metadata where it is given: the serve engine passes
+    its cut's head request id.
     """
     import jax
     import jax.numpy as jnp
@@ -1319,9 +1373,7 @@ def solve_instances(
     with TraceAnnotation("repro.search.prep", **span):
         cur_sols, scheds = [], []
         for inst, init_list in zip(instances, inits):
-            sols = [memory_update(inst, s, refresh_every=params.mem_refresh_every,
-                                  scalar=params.mem_update_scalar)
-                    for s in init_list]
+            sols = [_alg3(inst, s, params, span) for s in init_list]
             sc = [exact_schedule(inst, s) for s in sols]
             if not all(x is not None for x in sc):
                 raise ValueError("initial solutions must be acyclic")
@@ -1350,6 +1402,7 @@ def solve_instances(
         compile_s = 0.0
 
         state = {k: np.stack([st[k] for st in states]) for k in states[0]}
+        feasible = _feasible_record(state)   # (I, W, ...)
         with enable_x64():
             ia_j = {k: jnp.asarray(v) for k, v in batch.arrays().items()}
 
@@ -1440,22 +1493,21 @@ def solve_instances(
                         if done[i]:
                             continue
                         sub = {k: state[k][i] for k in state}
+                        feas = {k: v[i] for k, v in feasible.items()}  # views
                         for w in range(w_count):
                             if not sub["active"][w]:
                                 continue
                             sol_w = unpack_solution(packs[i], sub["seq"],
                                                     sub["seq_len"], sub["assign"],
                                                     sub["mem"], w)
-                            sol_w = memory_update(
-                                instances[i], sol_w,
-                                refresh_every=params.mem_refresh_every,
-                                scalar=params.mem_update_scalar)
+                            sol_w = _alg3(instances[i], sol_w, params, span)
                             sched_w = exact_schedule(instances[i], sol_w)
                             if sched_w is None:
                                 raise RuntimeError(
                                     "memory_update returned a cyclic solution")
                             n_exact_host[i] += 1
                             _write_walk(packs[i], sub, w, sol_w, sched_w)
+                            _note_feasible(feas, sub, w)
                             if sched_w.makespan < sub["best_mk"][w] - 1e-9:
                                 sub["best_mk"][w] = sched_w.makespan
                                 sub["best_seq"][w] = sub["seq"][w]
@@ -1497,8 +1549,9 @@ def solve_instances(
                 for w in range(w_count)
             ]
             if mem_updates_on:
-                best_sols, best_mk = _repair_bests(instances[i], params,
-                                                   best_sols, best_mk)
+                best_sols, best_mk = _repair_bests(
+                    instances[i], params, packs[i], best_sols, best_mk,
+                    {k: v[i] for k, v in feasible.items()}, span)
             gi = int(np.argmin(best_mk))
             _maybe_sanitize(instances[i], best_sols[gi],
                             f"solve_instances final best (instance {i})",

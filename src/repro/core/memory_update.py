@@ -38,8 +38,17 @@ lifetimes from *before* the placement it is probing, and the placement
 itself shifts durations — but it usually finds nothing and costs one extra
 DP + peaks sweep against the ~``n_data/refresh_every`` DPs of the update
 pass itself.
+
+``ALG3`` counts what the procedure does, alike on both paths: ``calls``,
+``blocks`` (candidate blocks placed, in a fast tier or left in the slow
+one), ``refused`` (of those, blocks that the capacity probe turned away
+from a faster compatible tier) and ``evicted`` (demotions by the
+epilogue).
 """
 from __future__ import annotations
+
+import collections
+import threading
 
 import numpy as np
 
@@ -52,7 +61,11 @@ from .solution import (
     memory_peaks,
 )
 
-__all__ = ["memory_update"]
+__all__ = ["ALG3", "memory_update"]
+
+ALG3: "collections.Counter[str]" = collections.Counter()
+"""Algorithm 3's work since start-up (the module docstring names the keys)."""
+_ALG3_LOCK = threading.Lock()   # the serve engine runs it on several threads
 
 
 def _tier_events(
@@ -96,17 +109,23 @@ def memory_update(
     verify-and-evict epilogue, so the returned allocation is always
     capacity-feasible under its exact schedule.
     """
+    tally = collections.Counter(calls=1)
     if scalar:
-        out = _memory_update_scalar(inst, sol, refresh_every)
+        out = _memory_update_scalar(inst, sol, refresh_every, tally)
     else:
-        out = _memory_update_fast(inst, sol, refresh_every)
-    return _capacity_repair(inst, out)
+        out = _memory_update_fast(inst, sol, refresh_every, tally)
+    out = _capacity_repair(inst, out, tally)
+    with _ALG3_LOCK:
+        ALG3.update(tally)
+    return out
 
 
-def _capacity_repair(inst: Instance, sol: Solution) -> Solution:
+def _capacity_repair(inst: Instance, sol: Solution,
+                     tally: collections.Counter) -> Solution:
     """Verify peaks under the exact schedule; demote least-critical blocks
-    out of overflowing finite tiers until every capacity holds.  Mutates and
-    returns ``sol`` (already a copy inside :func:`memory_update`)."""
+    out of overflowing finite tiers until every capacity holds, counting
+    each demotion in ``tally["evicted"]``.  Mutates and returns ``sol``
+    (already a copy inside :func:`memory_update`)."""
     if not (~np.isinf(inst.mem_cap)).any():
         return sol
     level_order = np.argsort(inst.mem_level, kind="stable")
@@ -134,6 +153,7 @@ def _capacity_repair(inst: Instance, sol: Solution) -> Solution:
                 tiers_tried=tuple(int(t) for t in level_order
                                   if inst.data_mem_ok[d, t]))
         sol.mem[d] = slower[0]
+        tally["evicted"] += 1
 
 
 # --------------------------------------------------------------------------- #
@@ -185,7 +205,8 @@ def _fits_fast(times: np.ndarray, deltas: np.ndarray, b: float, e: float,
     return not bool((run > cap + 1e-9).any())
 
 
-def _memory_update_fast(inst: Instance, sol: Solution, refresh_every: int) -> Solution:
+def _memory_update_fast(inst: Instance, sol: Solution, refresh_every: int,
+                        tally: collections.Counter) -> Solution:
     sol = sol.copy()
     # line 3: InitMemory — slowest compatible tier for every block
     slow_rank = np.argsort(-inst.mem_level)
@@ -218,6 +239,8 @@ def _memory_update_fast(inst: Instance, sol: Solution, refresh_every: int) -> So
     while cursor < len(order):
         d = int(order[cursor])
         cursor += 1
+        tally["blocks"] += 1
+        refused = False
         for m in fast_order:
             if not inst.data_mem_ok[d, m]:
                 continue
@@ -228,7 +251,9 @@ def _memory_update_fast(inst: Instance, sol: Solution, refresh_every: int) -> So
                 deltas[m] = np.append(deltas[m], (sizes[d], -sizes[d]))
                 placed_since_refresh += 1
                 break
+            refused = True
         # else: stays in the slow tier (always feasible)
+        tally["refused"] += refused
 
         if placed_since_refresh >= refresh_every and cursor < len(order):
             placed_since_refresh = 0
@@ -245,7 +270,8 @@ def _memory_update_fast(inst: Instance, sol: Solution, refresh_every: int) -> So
 # --------------------------------------------------------------------------- #
 # scalar oracle (the original implementation, kept verbatim)                   #
 # --------------------------------------------------------------------------- #
-def _memory_update_scalar(inst: Instance, sol: Solution, refresh_every: int) -> Solution:
+def _memory_update_scalar(inst: Instance, sol: Solution, refresh_every: int,
+                          tally: collections.Counter) -> Solution:
     sol = sol.copy()
     # line 3: InitMemory — slowest compatible tier for every block
     slow_rank = np.argsort(-inst.mem_level)
@@ -283,7 +309,9 @@ def _memory_update_scalar(inst: Instance, sol: Solution, refresh_every: int) -> 
                 best_key, best_d = key, d
         d = best_d
         pending.discard(d)
+        tally["blocks"] += 1
 
+        refused = False
         for m in fast_order:
             if not inst.data_mem_ok[d, m]:
                 continue
@@ -293,7 +321,9 @@ def _memory_update_scalar(inst: Instance, sol: Solution, refresh_every: int) -> 
                 events[m].append((death[d], -float(inst.data_size[d])))
                 placed_since_refresh += 1
                 break
+            refused = True
         # else: stays in the slow tier (always feasible)
+        tally["refused"] += refused
 
         if placed_since_refresh >= refresh_every and pending:
             placed_since_refresh = 0

@@ -7,8 +7,9 @@ threefry key, incumbents, eval/iteration counters — lives in one host
 numpy dict, and every launch is a pure function of that dict.  A
 :class:`SearchCheckpoint` snapshots it (plus the host-tracked trajectory:
 per-walk histories, global incumbent history, crit-bucket and Alg-3
-counters) at a sync boundary; resuming from the snapshot replays the
-remaining launches **bit-identically** — the resumed run's final result
+counters, each walk's best capacity-feasible schedule) at a sync
+boundary; resuming from the snapshot replays the remaining launches
+**bit-identically** — the resumed run's final result
 equals the uncrashed run's, field for field, under an iteration/eval
 budget (wall-clock fields excepted, and a wall-clock ``time_limit`` stop
 is carried over, not restarted: resumed elapsed includes pre-crash
@@ -38,7 +39,7 @@ __all__ = [
     "load",
 ]
 
-_VERSION = 1
+_VERSION = 2   # 2: each walk's best feasible schedule (``feasible``)
 
 
 class CheckpointMismatch(ValueError):
@@ -82,12 +83,14 @@ class SearchCheckpoint:
     g_hist: list             # [(iteration, makespan)] global incumbent history
     histories: list          # per-walk incumbent histories
     state: dict              # the packed walk-state pytree (numpy copies)
+    feasible: dict           # per-walk best feasible rows and makespans
 
 
 def snapshot(*, instance_fp: int, params_fp: int, walks: int,
              sync_index: int, crit_cap: int, elapsed: float,
              n_exact_host: int, g_best: float, init_mk_min: float,
-             g_hist, histories, state: dict) -> SearchCheckpoint:
+             g_hist, histories, state: dict,
+             feasible: dict) -> SearchCheckpoint:
     """Deep-copy the mutable pieces so later in-place updates by the
     driver cannot bleed into an already-taken checkpoint."""
     return SearchCheckpoint(
@@ -100,6 +103,7 @@ def snapshot(*, instance_fp: int, params_fp: int, walks: int,
         g_hist=[(int(i), float(m)) for i, m in g_hist],
         histories=[[(int(i), float(m)) for i, m in h] for h in histories],
         state={k: np.array(v, copy=True) for k, v in state.items()},
+        feasible={k: np.array(v, copy=True) for k, v in feasible.items()},
     )
 
 
@@ -125,6 +129,8 @@ def save(ckpt: SearchCheckpoint, path: str) -> str:
              "crit_cap", "elapsed", "n_exact_host", "g_best", "init_mk_min",
              "g_hist", "histories")}
     arrays = {f"state_{k}": np.asarray(v) for k, v in ckpt.state.items()}
+    arrays.update({f"feasible_{k}": np.asarray(v)
+                   for k, v in ckpt.feasible.items()})
     d = os.path.dirname(os.path.abspath(path)) or "."
     os.makedirs(d, exist_ok=True)
     fd, tmp = tempfile.mkstemp(dir=d, suffix=".ckpt.tmp")
@@ -143,8 +149,10 @@ def save(ckpt: SearchCheckpoint, path: str) -> str:
 def load(path: str) -> SearchCheckpoint:
     with np.load(path) as z:
         meta = json.loads(bytes(np.asarray(z["meta"])).decode())
-        state = {}
+        state, feasible = {}, {}
         for k in z.files:
+            if k.startswith("feasible_"):
+                feasible[k[len("feasible_"):]] = np.asarray(z[k])
             if not k.startswith("state_"):
                 continue
             v = np.asarray(z[k])
@@ -154,4 +162,4 @@ def load(path: str) -> SearchCheckpoint:
     meta["g_hist"] = [(int(i), float(m)) for i, m in meta["g_hist"]]
     meta["histories"] = [[(int(i), float(m)) for i, m in h]
                          for h in meta["histories"]]
-    return SearchCheckpoint(state=state, **meta)
+    return SearchCheckpoint(state=state, feasible=feasible, **meta)

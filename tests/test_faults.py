@@ -194,7 +194,9 @@ def _toy_checkpoint() -> fckpt.SearchCheckpoint:
         histories=[[(0, 60.0)], [(4, 50.0), (12, 41.5)]],
         state={"best_mk": np.array([41.5, 50.0]),
                "assign": np.arange(8).reshape(2, 4),
-               "key": np.array([1, 2], dtype=np.uint32)})
+               "key": np.array([1, 2], dtype=np.uint32)},
+        feasible={"mk": np.array([44.0, 52.5]),
+                  "assign": np.arange(8, 16).reshape(2, 4)})
 
 
 def test_checkpoint_save_load_roundtrip(tmp_path):
@@ -209,6 +211,9 @@ def test_checkpoint_save_load_roundtrip(tmp_path):
     for k in ck.state:
         assert np.array_equal(back.state[k], ck.state[k])
         assert back.state[k].dtype == np.asarray(ck.state[k]).dtype
+    assert set(back.feasible) == set(ck.feasible)
+    for k in ck.feasible:
+        assert np.array_equal(back.feasible[k], ck.feasible[k])
 
 
 def test_checkpoint_snapshot_is_deep():
@@ -216,7 +221,8 @@ def test_checkpoint_snapshot_is_deep():
     ck = fckpt.snapshot(
         instance_fp=1, params_fp=2, walks=1, sync_index=0, crit_cap=8,
         elapsed=0.0, n_exact_host=0, g_best=5.0, init_mk_min=5.0,
-        g_hist=[], histories=[[]], state=state)
+        g_hist=[], histories=[[]], state=state,
+        feasible={"mk": np.array([5.0])})
     state["mk"][0] = -1.0
     assert ck.state["mk"][0] == 5.0
 
@@ -233,7 +239,7 @@ def test_check_compatible_rejects_mismatches():
 # --------------------------------------------------------------------------- #
 # crash/resume bit-parity (device engine)                                     #
 # --------------------------------------------------------------------------- #
-def _resume_roundtrip(walks: int, tmp_path):
+def _resume_roundtrip(walks: int, tmp_path, mem_update_period=None):
     jax = pytest.importorskip("jax")  # noqa: F841
     from repro.core.device_search import (
         MEM_UPDATE_DISABLED,
@@ -245,7 +251,8 @@ def _resume_roundtrip(walks: int, tmp_path):
     inst = random_instance(0, n_tasks=40, n_data=100)
     # iteration-bound only, so the run spans several sync boundaries
     params = TSParams(seed=3, max_unimproved=10**9, time_limit=1e9, top_k=5,
-                      max_iters=40, mem_update_period=MEM_UPDATE_DISABLED)
+                      max_iters=40,
+                      mem_update_period=mem_update_period or MEM_UPDATE_DISABLED)
     cfg = DeviceConfig(sync_every=16, crit_cap=32)
     inits = [construct_greedy(inst, STRATEGIES[w % len(STRATEGIES)], rng=3 + w)
              for w in range(walks)]
@@ -282,6 +289,12 @@ def _resume_roundtrip(walks: int, tmp_path):
 
 def test_crash_resume_bit_parity_w1(tmp_path):
     _resume_roundtrip(1, tmp_path)
+
+
+def test_crash_resume_bit_parity_with_alg3_at_syncs(tmp_path):
+    """With Algorithm 3 at every sync the checkpoint carries each walk's
+    best feasible schedule, which the final repair may serve."""
+    _resume_roundtrip(1, tmp_path, mem_update_period=1)
 
 
 @pytest.mark.slow

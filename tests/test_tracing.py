@@ -9,8 +9,10 @@ with every scope taken out.
 """
 import asyncio
 import contextlib
+import dataclasses
 import glob
 import os
+import pathlib
 import re
 import time
 
@@ -21,13 +23,18 @@ jax = pytest.importorskip("jax")
 from repro.core import Budget, TSParams, random_instance  # noqa: E402
 from repro.core.api import multiwalk_inits  # noqa: E402
 from repro.core.device_search import (  # noqa: E402
+    REPAIRS,
     DeviceConfig,
     _round_loop,
     _series_buffers,
     pack_state,
+    solve_instances,
 )
+from repro.core.greedy import construct_greedy  # noqa: E402
+from repro.core.memory_update import ALG3, memory_update  # noqa: E402
 from repro.core.solution import exact_schedule  # noqa: E402
 from repro.instances.batch import ia_from_pack, pack_instance  # noqa: E402
+from repro.instances.suites import load_npz  # noqa: E402
 from repro.serve import (  # noqa: E402
     BatchPolicy,
     Engine,
@@ -44,6 +51,9 @@ EXECUTE = ("repro.search.prep", "repro.search.launch", "repro.search.readback",
            "repro.search.sync", "repro.search.finish", "repro.engine.fanout")
 SCOPES = ("ts_round", "ts_move_gen", "ts_approx_eval", "ts_exact_eval",
           "ts_perturb", "ts_commit")
+# tight (20% fast memory) and roomy instances of tests/test_feasible_best.py
+TIERS = load_npz(str(pathlib.Path(__file__).parent / "fixtures"
+                     / "feasible_best_instances.npz"))
 
 
 def recorded(log_dir) -> list:
@@ -188,3 +198,63 @@ def test_round_program_scopes_change_metadata_only(monkeypatch):
     plain = lower_round_program()
     assert "ts_round" not in plain.as_text(debug_info=True)
     assert program_text(plain.compile()) == scoped
+
+
+def solve_group(prefix: str, iters: int, cut=None) -> None:
+    """Four instances of the fixture in one batch, one sync per round."""
+    insts = [i for i in TIERS if i.name.startswith(prefix)]
+    params = dataclasses.replace(TSParams(), max_iters=iters)
+    inits = [multiwalk_inits(inst, 4, s)[0] for s, inst in enumerate(insts)]
+    solve_instances(insts, inits, params,
+                    config=DeviceConfig(sync_every=1, crit_cap=64),
+                    seeds=list(range(len(insts))), cut=cut)
+
+
+def test_alg3_spans_nest_in_their_cut_and_a_repair_falls_back(tmp_path):
+    """Two rounds on tight instances: Algorithm 3 runs on every walk start
+    (``prep``), on every walk after the first round (``sync``) and on the
+    device bests over capacity (``finish``); one repair comes out worse
+    than its walk's best feasible schedule, which is served instead."""
+    before = REPAIRS.copy()
+    with profiled(tmp_path):
+        solve_group("fft8-16", iters=2, cut=7)
+    delta = REPAIRS - before
+    assert delta["walks"] == 16 and delta["infeasible"] >= delta["fallback"] >= 1
+
+    spans = recorded(tmp_path)
+    alg3 = [(s, e, meta) for n, s, e, meta in spans if n == "repro.search.alg3"]
+    assert all(meta.get("cut") == 7 for *_, meta in alg3)
+    parents = {name: [(s, e) for n, s, e, meta in spans
+                      if n == name and meta.get("cut") == 7]
+               for name in ("repro.search.prep", "repro.search.sync",
+                            "repro.search.finish")}
+    where = {name: 0 for name in parents}
+    for s, e, _ in alg3:
+        name, = [n for n, ivs in parents.items()
+                 if any(lo <= s <= e <= hi for lo, hi in ivs)]
+        where[name] += 1
+    assert where["repro.search.prep"] == 16
+    assert where["repro.search.sync"] == 16
+    assert where["repro.search.finish"] == delta["infeasible"]
+
+
+def test_no_repair_falls_back_on_roomy_memory():
+    before = REPAIRS.copy()
+    solve_group("roomy40", iters=1)
+    delta = REPAIRS - before
+    assert delta["walks"] == 16
+    assert delta["infeasible"] == delta["fallback"] == 0
+
+
+@pytest.mark.parametrize("name", ["fft8-16-2", "layered40-16-4"])
+def test_alg3_counts_alike_on_both_paths(name):
+    inst = next(i for i in TIERS if i.name == name)
+    sol = construct_greedy(inst, "slack_first", rng=0)
+    counts = []
+    for scalar in (False, True):
+        before = ALG3.copy()
+        out = memory_update(inst, sol, refresh_every=8, scalar=scalar)
+        counts.append((ALG3 - before, out.mem.tolist()))
+    assert counts[0] == counts[1]
+    delta = counts[0][0]
+    assert delta["calls"] == 1 and delta["blocks"] >= delta["refused"] > 0
