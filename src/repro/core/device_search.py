@@ -245,6 +245,129 @@ def _mix32_jnp(jnp, *words):
     return h
 
 
+def _take_w(arr2d, idx):
+    """arr2d (W, n), idx (W, ...) → gathered values per walk."""
+    import jax.numpy as jnp
+
+    flat = idx.reshape(arr2d.shape[0], -1)
+    return jnp.take_along_axis(arr2d, flat, axis=1).reshape(idx.shape)
+
+
+def _new_seq_at(seq_dst, u, j, k, cc, i):
+    """Element ``i`` of each move's post-move destination sequence
+    (``eval_batch._new_seq_at`` verbatim)."""
+    import jax.numpy as jnp
+
+    t = i - (i > j)
+    orig = t + ((~cc) & (t >= k))
+    g = jnp.take_along_axis(
+        seq_dst, jnp.clip(orig, 0, seq_dst.shape[-1] - 1)[..., None],
+        axis=-1)[..., 0]
+    return jnp.where(i == j, u, g)
+
+
+def _window_estimates(ia: dict, seq, seq_len, mem, dur_all, r_all, q_all,
+                      mv: dict):
+    """The approximate evaluation of every move: ``(est, finite)``, (W, M).
+
+    ``mv`` holds each move's ``task``, ``src_s``, ``dst_p``, ``dst_s``,
+    ``cc`` and ``valid`` (W, M), with masked slots holding in-range indices.
+    Heads are recomputed along the move's window of the destination
+    sequence (old heads elsewhere), and ``est`` is the largest new head plus
+    old tail over the window; ``finite`` is whether the moved task's
+    duration on its destination core is.
+
+    What a move reads of a task does not depend on the move, so it is
+    tabulated once per call and read per move as a row: each task's
+    predecessors' durations and old finishes ``(W, n_b, Dp)``, and each
+    (task, core)'s re-priced duration ``(W, n_b, p_b)``.  A predecessor is
+    in the window if it equals a task placed at an earlier step of the same
+    move.  Every add and max is the scalar oracle's, in its order.
+    """
+    import jax.numpy as jnp
+
+    pred_mat, proc_time, io_cost = ia["pred_mat"], ia["proc_time"], ia["io_cost"]
+    u, k, b, j, cc, valid = (mv[key] for key in
+                             ("task", "src_s", "dst_p", "dst_s", "cc", "valid"))
+    W, n_b, s_b = seq.shape[0], proc_time.shape[0], seq.shape[2]
+    n_m = io_cost.shape[2]
+
+    def rows(tab, x):
+        """tab (W, n_b, D), x (W, M) → each move's row (W, M, D)."""
+        return jnp.take_along_axis(tab, x[:, :, None], axis=1)
+
+    def reprice(blk_mat):
+        """Move-in or move-out time of every (walk, task, core) under the
+        walk's allocation: the task's blocks priced on the core, added left
+        to right over the zero-padded width (the scalar order)."""
+        ok = blk_mat >= 0
+        bsafe = jnp.where(ok, blk_mat, 0)                     # (n_b, L)
+        memv = mem[:, bsafe][:, :, None, :]                   # (W, n_b, 1, L)
+        per_tier = jnp.moveaxis(io_cost[bsafe], 1, 2)         # (n_b, p_b, L, n_m)
+        cost = per_tier[None, ..., 0]
+        for m in range(1, n_m):
+            cost = jnp.where(memv == m, per_tier[None, ..., m], cost)
+        vals = jnp.where(ok[None, :, None, :], cost, 0.0)     # (W, n_b, p_b, L)
+        tot = jnp.zeros(vals.shape[:3], jnp.float64)
+        for jj in range(vals.shape[3]):
+            tot = tot + vals[..., jj]
+        return tot
+
+    pclip = jnp.clip(pred_mat, 0, n_b - 1)
+    pred_dur = dur_all[:, pclip]                              # (W, n_b, Dp)
+    pred_fin = r_all[:, pclip] + pred_dur
+    d_tab = reprice(ia["in_blk"]) + proc_time[None] + reprice(ia["out_blk"])
+
+    seq_dst = jnp.take_along_axis(seq, b[:, :, None], axis=1)    # (W, M, s_b)
+    dur_old = _take_w(dur_all, u)
+    q_old = _take_w(q_all, u)
+    d_cc = _take_w(d_tab.reshape(W, -1), u * proc_time.shape[1] + b)
+    dur_u = jnp.where(cc, d_cc, dur_old)
+    q_u = jnp.where(cc, q_old - dur_old + d_cc, q_old)
+    finite = jnp.isfinite(dur_u)
+    new_len = jnp.take_along_axis(seq_len, b, axis=1) + cc
+    w_lo = jnp.where(cc, j, jnp.minimum(k, j))
+    w_hi = jnp.minimum(new_len, w_lo + APPROX_WINDOW)
+    est = jnp.zeros(u.shape, jnp.float64)
+    xp = jnp.take_along_axis(
+        seq_dst, jnp.clip(w_lo - 1, 0, s_b - 1)[..., None], axis=2)[..., 0]
+    xp = jnp.clip(xp, 0, n_b - 1)
+    prev_finish = jnp.where(
+        w_lo > 0, _take_w(r_all, xp) + _take_w(dur_all, xp), 0.0)
+    placed = []    # (task, new head) of each earlier window step
+    for s in range(APPROX_WINDOW):
+        idxp = w_lo + s
+        act = valid & (idxp < w_hi)
+        x = jnp.where(act, _new_seq_at(seq_dst, u, j, k, cc, idxp), 0)
+        preds = pred_mat[x]                                   # (W, M, Dp)
+        # the active steps of a move are a prefix of its window, so an
+        # active step meets only active ones; the latest placement counts
+        in_win = jnp.zeros(preds.shape, bool)
+        head_at = jnp.zeros(preds.shape, jnp.float64)
+        for xt, ht in placed:
+            hit = preds == xt[..., None]
+            in_win = in_win | hit
+            head_at = jnp.where(hit, ht[..., None], head_at)
+        dsel = jnp.where(preds == u[..., None], dur_u[..., None],
+                         rows(pred_dur, x))
+        f = jnp.where(preds >= 0,
+                      jnp.where(in_win, head_at + dsel, rows(pred_fin, x)),
+                      -jnp.inf)
+        head = jnp.maximum(prev_finish, f.max(axis=2))
+        placed.append((x, head))
+        is_u = x == u
+        dx = jnp.where(is_u, dur_u, _take_w(dur_all, x))
+        qx = jnp.where(is_u, q_u, _take_w(q_all, x))
+        est = jnp.where(act, jnp.maximum(est, head + qx), est)
+        prev_finish = jnp.where(act, head + dx, prev_finish)
+    tailm = valid & (w_hi < new_len)
+    x_t = _new_seq_at(seq_dst, u, j, k, cc, w_hi)
+    x_t = jnp.clip(jnp.where(tailm, x_t, 0), 0, n_b - 1)
+    est = jnp.where(tailm, jnp.maximum(est, prev_finish + _take_w(q_all, x_t)),
+                    est)
+    return jnp.where(finite & valid, est, jnp.inf), finite
+
+
 def _round_loop(ia: dict, w_count: int, params: TSParams,
                 crit_cap: int, rounds: int, cfg: DeviceConfig):
     """Build the ``rounds``-bounded while_loop over full tabu rounds.
@@ -266,8 +389,6 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
     ia = {k: jnp.asarray(v) for k, v in ia.items()}  # no-op on tracers
     pred_mat = ia["pred_mat"]
     succ_mat = ia["succ_mat"]
-    in_blk = ia["in_blk"]
-    out_blk = ia["out_blk"]
     in_idx = ia["in_idx"]
     in_owner = ia["in_owner"]
     in_valid = ia["in_valid"]
@@ -289,23 +410,13 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
     M_n7 = 2 * C
     M_cc = C * p_b * (NPOS + 1)
     M = M_n7 + M_cc
-    WIN = APPROX_WINDOW
     R = rounds
     max_unimp = params.max_unimproved
     max_iters = _NONE if params.max_iters is None else np.int64(params.max_iters)
     max_evals = _NONE if params.max_evals is None else np.int64(params.max_evals)
-    Din = in_blk.shape[1]
-    Dout = out_blk.shape[1]
 
     wi = jnp.arange(W)
-    f64 = jnp.float64
     INF = jnp.inf
-
-    def take_w(arr2d, idx):
-        """arr2d (W, n), idx (W, ...) → gathered values per walk."""
-        flat = idx.reshape(W, -1)
-        out = jnp.take_along_axis(arr2d, flat, axis=1)
-        return out.reshape(idx.shape)
 
     def durations(assign_rows, mem_rows):
         """``solution.durations`` replayed bit-exactly per row: global
@@ -365,28 +476,6 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
         # but a t_safe of n_b inside the slice writes to col n_b only ✓
         return mp[:, :n_b], ms[:, :n_b]
 
-    def new_seq_at(seq_dst, u, j, k, cc, i):
-        """Element ``i`` of each move's post-move destination sequence
-        (``eval_batch._new_seq_at`` verbatim)."""
-        t = i - (i > j)
-        orig = t + ((~cc) & (t >= k))
-        g = jnp.take_along_axis(
-            seq_dst, jnp.clip(orig, 0, s_b - 1)[..., None], axis=-1)[..., 0]
-        return jnp.where(i == j, u, g)
-
-    def reprice(mem_w, u, b, blk_mat):
-        """Vectorized AT re-pricing with the scalar sequential sum order:
-        per-move block list, left-to-right adds over zero-padded width."""
-        blocks = blk_mat[jnp.clip(u, 0, n_b - 1)]            # (W, M, L)
-        ok = blocks >= 0
-        bsafe = jnp.where(ok, blocks, 0)
-        memv = mem_w[wi[:, None, None], bsafe]               # (W, M, L)
-        vals = jnp.where(ok, io_cost[bsafe, b[..., None], memv], 0.0)
-        tot = jnp.zeros(vals.shape[:2], f64)
-        for jj in range(vals.shape[2]):
-            tot = tot + vals[:, :, jj]
-        return tot
-
     # ---------------------------------------------------------------- round
     def round_body(st):
         it = st["it"] + 1
@@ -413,7 +502,7 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
             col = jnp.arange(s_b)[None, None, :]
             validp = col < seq_len[:, :, None]
             seq_c = jnp.clip(seq, 0, n_b - 1)
-            c_on = jnp.where(validp, take_w(crit, seq_c.reshape(W, -1)
+            c_on = jnp.where(validp, _take_w(crit, seq_c.reshape(W, -1)
                                             ).reshape(W, p_b, s_b), False)
             prev = jnp.pad(c_on[:, :, :-1], ((0, 0), (0, 0), (1, 0)))
             nxt = jnp.pad(c_on[:, :, 1:], ((0, 0), (0, 0), (0, 1)))
@@ -446,11 +535,11 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
             crit_ok = jnp.take_along_axis(crit, crit_order, axis=1)
             u_cc = crit_order.astype(_I32)
             mach, pos = seq_positions(seq, seq_len)
-            a_cc = take_w(mach, u_cc)                                     # (W, C)
-            k_cc = take_w(pos, u_cc)
-            r_starts = jnp.where(validp, take_w(r_all, seq_c.reshape(W, -1)
+            a_cc = _take_w(mach, u_cc)                                     # (W, C)
+            k_cc = _take_w(pos, u_cc)
+            r_starts = jnp.where(validp, _take_w(r_all, seq_c.reshape(W, -1)
                                                 ).reshape(W, p_b, s_b), INF)
-            r_u = take_w(r_all, u_cc)                                     # (W, C)
+            r_u = _take_w(r_all, u_cc)                                     # (W, C)
             anchor = jax.vmap(jax.vmap(jnp.searchsorted, in_axes=(0, None)),
                               in_axes=(0, 0))(r_starts, r_u)              # (W, p_b, C)
             anchor = jnp.moveaxis(anchor, 1, 2)                           # (W, C, p_b)
@@ -495,65 +584,10 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
 
         # ---------------- approximate evaluation ------------------------ #
         with jax.named_scope("ts_approx_eval"):
-            seq_dst = jnp.take_along_axis(
-                seq, mv_dst_p[:, :, None], axis=1)                        # (W, M, s_b)
-            dur_u = take_w(dur_all, mv_task)
-            q_u = take_w(q_all, mv_task)
-            t_in_cc = reprice(mem, mv_task, mv_dst_p, in_blk)
-            t_out_cc = reprice(mem, mv_task, mv_dst_p, out_blk)
-            d_cc = t_in_cc + proc_time[mv_task, mv_dst_p] + t_out_cc
-            dur_u = jnp.where(mv_cc, d_cc, dur_u)
-            q_u = jnp.where(mv_cc, take_w(q_all, mv_task)
-                            - take_w(dur_all, mv_task) + d_cc, q_u)
-            finite = jnp.isfinite(dur_u)
-            dst_len = jnp.take_along_axis(seq_len, mv_dst_p, axis=1)
-            new_len = dst_len + mv_cc
-            w_lo = jnp.where(mv_cc, mv_dst_s, jnp.minimum(mv_src_s, mv_dst_s))
-            w_hi = jnp.minimum(new_len, w_lo + WIN)
-            est = jnp.zeros((W, M), f64)
-            xp = jnp.take_along_axis(
-                seq_dst, jnp.clip(w_lo - 1, 0, s_b - 1)[..., None], axis=2)[..., 0]
-            xp = jnp.clip(xp, 0, n_b - 1)
-            prev_finish = jnp.where(
-                w_lo > 0, take_w(r_all, xp) + take_w(dur_all, xp), 0.0)
-            win_of = jnp.full((W, M, n_b + 1), -1, jnp.int8)
-            win_heads = jnp.zeros((W, M, WIN), f64)
-            mi = jnp.arange(M)[None, :]
-            wim = jnp.broadcast_to(wi[:, None], (W, M))
-            for s in range(WIN):
-                idxp = w_lo + s
-                act = mv_valid & (idxp < w_hi)
-                x = new_seq_at(seq_dst, mv_task, mv_dst_s, mv_src_s, mv_cc, idxp)
-                x = jnp.where(act, x, 0)
-                preds = pred_mat[x]                                       # (W, M, Dp)
-                pok = preds >= 0
-                psafe = jnp.where(pok, preds, n_b)
-                tpos = jnp.take_along_axis(win_of, psafe, axis=2)         # (W, M, Dp)
-                in_win = tpos >= 0
-                head_at = jnp.take_along_axis(
-                    win_heads, jnp.clip(tpos, 0, WIN - 1).astype(jnp.int32), axis=2)
-                pclip = jnp.clip(preds, 0, n_b - 1)
-                dsel = jnp.where(preds == mv_task[..., None],
-                                 dur_u[..., None], take_w(dur_all, pclip))
-                f_win = head_at + dsel
-                f_def = take_w(r_all, pclip) + take_w(dur_all, pclip)
-                f = jnp.where(pok, jnp.where(in_win, f_win, f_def), -INF)
-                head = jnp.maximum(prev_finish, f.max(axis=2))
-                win_of = win_of.at[wim, mi, jnp.where(act, x, n_b)].set(
-                    jnp.int8(s))
-                win_heads = win_heads.at[:, :, s].set(head)
-                is_u = x == mv_task
-                dx = jnp.where(is_u, dur_u, take_w(dur_all, x))
-                qx = jnp.where(is_u, q_u, take_w(q_all, x))
-                est = jnp.where(act, jnp.maximum(est, head + qx), est)
-                prev_finish = jnp.where(act, head + dx, prev_finish)
-            tailm = mv_valid & (w_hi < new_len)
-            x_t = new_seq_at(seq_dst, mv_task, mv_dst_s, mv_src_s, mv_cc, w_hi)
-            x_t = jnp.clip(jnp.where(tailm, x_t, 0), 0, n_b - 1)
-            est = jnp.where(tailm,
-                            jnp.maximum(est, prev_finish + take_w(q_all, x_t)),
-                            est)
-            est = jnp.where(finite & mv_valid, est, INF)
+            est, finite = _window_estimates(
+                ia, seq, seq_len, mem, dur_all, r_all, q_all,
+                {"task": mv_task, "src_s": mv_src_s, "dst_p": mv_dst_p,
+                 "dst_s": mv_dst_s, "cc": mv_cc, "valid": mv_valid})
 
             # ---------------- sort, tabu pre-filter ------------------------- #
             order = jnp.argsort(est, axis=1, stable=True)
@@ -607,8 +641,8 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
                 ccm = jnp.take_along_axis(cc_a, sel_idx, axis=1)
                 u = jnp.where(slot_ok, u, 0)
                 b = jnp.where(slot_ok, b, 0)
-                x = take_w(mpred, u)
-                y = take_w(msucc, u)
+                x = _take_w(mpred, u)
+                y = _take_w(msucc, u)
                 w3 = jnp.broadcast_to(wi[:, None], (W, kk))
                 k3 = jnp.broadcast_to(jnp.arange(kk)[None, :], (W, kk))
                 mp = jnp.concatenate(
@@ -799,7 +833,7 @@ def _round_loop(ia: dict, w_count: int, params: TSParams,
                                jnp.where(p_ok, p_mk, cur_mk))
 
             # tabu the destroyed configuration (accepted moves only)
-            mp_before = take_w(mpred, cm_u[:, None])[:, 0]
+            mp_before = _take_w(mpred, cm_u[:, None])[:, 0]
             destroyed = (cm_u.astype(jnp.int64) * p_b + cm_a) * (n_b + 2) \
                 + jnp.where(mp_before >= 0, mp_before, -2) + 2
             h_cc = _mix32_jnp(jnp, st["seed"], wi, it, jnp.uint32(1))
