@@ -8,9 +8,8 @@ family-independent lower bound.
 
     PYTHONPATH=src python examples/quickstart.py
 
-For the end-to-end training pipeline (HDATS planner -> remat policy -> jit
-train step -> checkpointed loop) see ``examples/train_100m.py`` and
-``examples/schedule_plan.py``.
+For the planner path (model MDFG -> HDATS plan -> ``jax.checkpoint``
+policy) see ``examples/schedule_plan.py``.
 """
 from repro import Budget, solve
 from repro.instances import generate, list_families, lower_bound, save_npz, sweep

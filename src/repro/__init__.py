@@ -1,5 +1,6 @@
-"""repro — HDATS (Ding et al., 2022) reproduction grown toward a
-production-scale JAX planning/training system.
+"""repro — HDATS (Ding et al., 2022): data allocation and task scheduling on
+heterogeneous multiprocessors under memory constraints, served as a
+memory-constrained DAG scheduler on the TPU.
 
 The supported solver surface lives here::
 
@@ -8,7 +9,7 @@ The supported solver surface lives here::
     report = solve(instance, method="tabu", budget=Budget(time_limit=10.0))
     report.makespan, report.solution, report.history
 
-Heavy subsystems (``repro.plan``, ``repro.kernels``, ``repro.runtime``, …)
+Heavy subsystems (``repro.plan``, ``repro.kernels``, ``repro.serve``, …)
 import JAX and are deliberately *not* pulled in by this module; import them
 explicitly.
 """

@@ -1,3 +1,7 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Device kernels of the scheduler.
+
+``schedule_dp`` holds the level-synchronous schedule-DP sweeps (forward
+start/finish times, backward tails) that the batched evaluator and the
+device search engine use for exact evaluation: an XLA form and a Pallas
+form of the same recursion.
+"""

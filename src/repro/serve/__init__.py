@@ -11,9 +11,6 @@ warm-pool :class:`~repro.serve.engine.Engine` runs them through
 overlapping host batch assembly with device compute, and streams anytime
 incumbents back per request.  Every served result is bit-identical to a
 solo ``repro.solve()`` at the same seed/budget/backend.
-
-(The LLM token-serving driver lives at ``repro.launch.model_serve`` —
-this package is the *scheduling* service.)
 """
 from .batcher import Batcher, BatchPolicy, CutBatch
 from .compile_cache import enable_compilation_cache

@@ -1,15 +1,18 @@
 """Planner bridge: residency/pipeline MDFG extraction + plan quality + lowering."""
-import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
+from repro.analysis.certify import certify_solution
 from repro.configs.base import SHAPE_CELLS
-from repro.configs.registry import get_config, get_smoke_config
+from repro.configs.registry import ARCH_IDS, get_config
 from repro.core import TSParams, exact_schedule, memory_feasible, solve
 from repro.plan import (
+    ACT_CLASSES,
+    ResidencyPlan,
     hbm_activation_budget,
     layer_costs,
     param_state_bytes,
@@ -22,6 +25,16 @@ from repro.plan import (
 from repro.plan.extract import contiguous_stage_map
 
 TRAIN = SHAPE_CELLS[0]
+
+
+def _scan_group(cfg) -> int:
+    """Largest of the small scan groups that divides the layer count."""
+    return next(g for g in (4, 3, 2, 1) if cfg.n_layers % g == 0)
+
+
+def _assert_certified(inst, rep):
+    cert = certify_solution(inst, rep.solution, reported_makespan=rep.makespan)
+    assert cert.ok, f"{inst.name}: {cert.summary()}"
 
 
 def test_layer_costs_scale_with_width():
@@ -45,15 +58,36 @@ def test_param_state_bytes_optimizer_choice():
         hbm_activation_budget(cfg, optimizer="adafactor")
 
 
-def test_residency_instance_is_valid_hdats():
-    cfg = get_config("mixtral-8x7b")
-    inst, meta = residency_instance(cfg, TRAIN, scan_group=4)
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_layer_costs_positive(arch):
+    cfg = get_config(arch)
+    for c in layer_costs(cfg, TRAIN):
+        assert c.flops_fwd > 0
+        assert all(v >= 0 for v in c.act_bytes.values())
+    assert param_state_bytes(cfg, optimizer="adamw") > 0
+    assert param_state_bytes(cfg, optimizer="adafactor") > 0
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_residency_instance_is_valid_hdats(arch):
+    cfg = get_config(arch)
+    inst, meta = residency_instance(cfg, TRAIN, scan_group=_scan_group(cfg))
     assert inst.n_tasks == 2 * meta["n_groups"]
     rep = solve(inst, "greedy:slack_first")
     sched = exact_schedule(inst, rep.solution)
     assert sched is not None and memory_feasible(inst, rep.solution, sched)
     # remat tier must be the most expensive per-byte access for this graph
     assert inst.access_time[0, 2] > inst.access_time[0, 0]
+    _assert_certified(inst, rep)
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_pipeline_instance_is_valid_hdats(arch):
+    cfg = get_config(arch)
+    inst, _ = pipeline_instance(cfg, TRAIN, n_stages=4, n_microbatches=6)
+    assert inst.n_tasks == 2 * 4 * 6  # fwd + bwd per stage and microbatch
+    rep = solve(inst, "greedy:slack_first")
+    _assert_certified(inst, rep)
 
 
 @pytest.mark.parametrize("arch", ["llama3-405b", "mamba2-780m", "recurrentgemma-2b"])
@@ -68,24 +102,39 @@ def test_plan_beats_or_matches_lb(arch):
     assert plan.scan_group >= 1 and cfg.n_layers % plan.scan_group == 0
 
 
-def test_plan_policy_lowers_and_compiles():
-    """The winning plan's checkpoint policy must actually lower via jax."""
-    cfg = get_smoke_config("qwen2.5-14b")
-    full = get_config("qwen2.5-14b")
-    plan = plan_residency(full, TRAIN, use_tabu=False)
-    policy = plan.policy()
-    from repro.models import arch_forward, arch_init_params, cross_entropy_loss
+def _toy_plan(save_names, offload_names) -> ResidencyPlan:
+    return ResidencyPlan(arch_id="toy", cell=TRAIN.name, scan_group=1,
+                         save_names=save_names, offload_names=offload_names,
+                         est_step_time=1.0, hbm_budget=1.0)
 
-    params = arch_init_params(cfg, jax.random.PRNGKey(0))
-    batch = {"tokens": jnp.zeros((2, 32), jnp.int32)}
-    labels = jnp.zeros((2, 32), jnp.int32)
 
-    def loss(p):
-        lg = arch_forward(cfg, p, batch, remat_policy=policy, scan_group=2)
-        return cross_entropy_loss(cfg, lg, labels)
+POLICY_FORMS = {
+    "save_only": _toy_plan(ACT_CLASSES[:2], ()),
+    "offload_and_save": _toy_plan(ACT_CLASSES[:1], ACT_CLASSES[1:3]),
+    "none": _toy_plan((), ()),
+}
 
-    val, grads = jax.value_and_grad(loss)(params)
+
+@pytest.mark.parametrize("form", sorted(POLICY_FORMS))
+def test_plan_policy_lowers_and_compiles(form):
+    """Each form of a plan's checkpoint policy lowers through jax.checkpoint
+    and differentiates to the same gradient as the unchecked function."""
+    policy = POLICY_FORMS[form].policy()
+    assert (policy is None) == (form == "none")
+    ws = jax.random.normal(jax.random.PRNGKey(0), (4, 16, 16)) / 4.0
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, 16))
+
+    def loss(w):
+        h = x
+        for i in range(w.shape[0]):
+            h = checkpoint_name(jnp.tanh(h @ w[i]), ACT_CLASSES[i])
+        return (h ** 2).sum()
+
+    val, grads = jax.jit(jax.value_and_grad(jax.checkpoint(loss, policy=policy)))(ws)
     assert bool(jnp.isfinite(val))
+    ref_val, ref_grads = jax.value_and_grad(loss)(ws)
+    np.testing.assert_allclose(val, ref_val, rtol=1e-6)
+    np.testing.assert_allclose(grads, ref_grads, rtol=1e-5, atol=1e-6)
 
 
 def test_contiguous_stage_map_balances():
