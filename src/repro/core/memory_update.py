@@ -14,8 +14,12 @@ Two implementations share these semantics:
 * the **fast path** (default) — criticalities for *all* pending blocks come
   from one segment sum per refresh, the most-critical-first pop order is a
   single lexsort over ``(-uses, size, d)`` (valid because criticality only
-  changes at refreshes), and the per-placement capacity probe is a
-  lexsort + cumsum over the tier's event arrays;
+  changes at refreshes), the per-placement capacity probe is a
+  lexsort + cumsum over the tier's event arrays, and every refresh after
+  the first ``exact_schedule``, the epilogue's included, re-times the
+  call's frozen machine order (``solution.FrozenOrder``: array sweeps over
+  the fixed combined graph, bit-equal to ``exact_schedule`` plus
+  ``heads_tails``, since only ``mem`` changes within a call);
 * the **scalar oracle** (``scalar=True``) — the original per-block Python
   loops, kept as the parity reference and the PR-2-faithful baseline for
   ``benchmarks/search_bench.py``.
@@ -42,8 +46,9 @@ pass itself.
 ``ALG3`` counts what the procedure does, alike on both paths: ``calls``,
 ``blocks`` (candidate blocks placed, in a fast tier or left in the slow
 one), ``refused`` (of those, blocks that the capacity probe turned away
-from a faster compatible tier) and ``evicted`` (demotions by the
-epilogue).
+from a faster compatible tier), ``evicted`` (demotions by the epilogue)
+and ``refreshes`` (schedule evaluations after a call's first, the
+epilogue's passes included).
 """
 from __future__ import annotations
 
@@ -54,6 +59,7 @@ import numpy as np
 
 from .mdfg import InfeasibleInstanceError, Instance
 from .solution import (
+    FrozenOrder,
     Solution,
     data_lifetimes,
     exact_schedule,
@@ -111,33 +117,41 @@ def memory_update(
     """
     tally = collections.Counter(calls=1)
     if scalar:
-        out = _memory_update_scalar(inst, sol, refresh_every, tally)
+        out, frozen = _memory_update_scalar(inst, sol, refresh_every, tally), None
     else:
-        out = _memory_update_fast(inst, sol, refresh_every, tally)
-    out = _capacity_repair(inst, out, tally)
+        out, frozen = _memory_update_fast(inst, sol, refresh_every, tally)
+    out = _capacity_repair(inst, out, tally, frozen)
     with _ALG3_LOCK:
         ALG3.update(tally)
     return out
 
 
-def _capacity_repair(inst: Instance, sol: Solution,
-                     tally: collections.Counter) -> Solution:
+def _capacity_repair(inst: Instance, sol: Solution, tally: collections.Counter,
+                     frozen: FrozenOrder | None) -> Solution:
     """Verify peaks under the exact schedule; demote least-critical blocks
     out of overflowing finite tiers until every capacity holds, counting
-    each demotion in ``tally["evicted"]``.  Mutates and returns ``sol``
-    (already a copy inside :func:`memory_update`)."""
+    each pass in ``tally["refreshes"]`` and each demotion in
+    ``tally["evicted"]``.  The schedule comes from the fast path's
+    ``frozen`` order, or, after the scalar oracle (``frozen`` None), from
+    the reference functions.  Mutates and returns ``sol`` (already a copy
+    inside :func:`memory_update`)."""
     if not (~np.isinf(inst.mem_cap)).any():
         return sol
     level_order = np.argsort(inst.mem_level, kind="stable")
     while True:
-        sched = exact_schedule(inst, sol)
-        assert sched is not None, "memory repair requires an acyclic solution"
+        if frozen is None:
+            sched = exact_schedule(inst, sol)
+            assert sched is not None, "memory repair requires an acyclic solution"
+        else:
+            sched = frozen.schedule(sol.mem)
+        tally["refreshes"] += 1
         peaks = memory_peaks(inst, sol, sched)
         over = np.nonzero(peaks > inst.mem_cap * (1 + 1e-6) + 1e-6)[0]
         if not len(over):
             return sol
         m = int(over[0])
-        _, _, _, crit = heads_tails(inst, sol, sched)
+        _, _, _, crit = (heads_tails(inst, sol, sched) if frozen is None
+                         else frozen.heads_tails(sched))
         uses = _block_uses(inst, crit)
         resident = np.nonzero(sol.mem == m)[0]
         # least critical last in pop order ⇒ evict from the reversed key
@@ -206,7 +220,10 @@ def _fits_fast(times: np.ndarray, deltas: np.ndarray, b: float, e: float,
 
 
 def _memory_update_fast(inst: Instance, sol: Solution, refresh_every: int,
-                        tally: collections.Counter) -> Solution:
+                        tally: collections.Counter
+                        ) -> tuple[Solution, FrozenOrder | None]:
+    """The allocation, and the frozen order its refreshes were timed on
+    (None where no tier is finite and nothing was timed)."""
     sol = sol.copy()
     # line 3: InitMemory — slowest compatible tier for every block
     slow_rank = np.argsort(-inst.mem_level)
@@ -216,13 +233,15 @@ def _memory_update_fast(inst: Instance, sol: Solution, refresh_every: int,
 
     fast_order = [int(m) for m in np.argsort(inst.mem_level) if not np.isinf(inst.mem_cap[m])]
     if not fast_order:
-        return sol
+        return sol, None
     # only blocks that *can* live in a finite (fast) tier are candidates
     cand_mask = inst.data_mem_ok[:, fast_order].any(axis=1)
 
     sched = exact_schedule(inst, sol)
     assert sched is not None, "memory_update requires an acyclic solution"
-    _, _, _, crit = heads_tails(inst, sol, sched)
+    # assign and proc_seq stay fixed from here on: re-time on the frozen order
+    frozen = FrozenOrder(inst, sol, sched)
+    _, _, _, crit = frozen.heads_tails(sched)
     birth, death = data_lifetimes(inst, sched)
     times, deltas = _tier_event_arrays(inst, sol, birth, death)
     sizes = inst.data_size
@@ -257,14 +276,14 @@ def _memory_update_fast(inst: Instance, sol: Solution, refresh_every: int,
 
         if placed_since_refresh >= refresh_every and cursor < len(order):
             placed_since_refresh = 0
-            sched = exact_schedule(inst, sol)
-            assert sched is not None
-            _, _, _, crit = heads_tails(inst, sol, sched)
+            tally["refreshes"] += 1
+            sched = frozen.schedule(sol.mem)
+            _, _, _, crit = frozen.heads_tails(sched)
             birth, death = data_lifetimes(inst, sched)
             times, deltas = _tier_event_arrays(inst, sol, birth, death)
             order = pop_order(order[cursor:], _block_uses(inst, crit))
             cursor = 0
-    return sol
+    return sol, frozen
 
 
 # --------------------------------------------------------------------------- #
@@ -327,6 +346,7 @@ def _memory_update_scalar(inst: Instance, sol: Solution, refresh_every: int,
 
         if placed_since_refresh >= refresh_every and pending:
             placed_since_refresh = 0
+            tally["refreshes"] += 1
             sched = exact_schedule(inst, sol)
             assert sched is not None
             _, _, _, crit = heads_tails(inst, sol, sched)

@@ -9,6 +9,8 @@ A solution is:
 
 ``exact_schedule`` is the paper's *exact evaluation* (O(V+E) DP).
 ``heads_tails`` computes R, Q, Slack (Eqs. 27–29) and the critical set.
+``FrozenOrder`` gives both for one fixed machine order under changing
+allocations, with array sweeps (Algorithm 3's refreshes).
 ``memory_peaks`` is the paper's discretized differential-array feasibility
 check (§IV-C): peak usage can only change at block move-in events.
 """
@@ -27,6 +29,7 @@ __all__ = [
     "durations",
     "exact_schedule",
     "heads_tails",
+    "FrozenOrder",
     "memory_peaks",
     "memory_feasible",
     "data_lifetimes",
@@ -169,6 +172,94 @@ def heads_tails(
     slack = sched.makespan - r - q
     critical = slack <= _EPS * max(1.0, sched.makespan)
     return r, q, slack, critical
+
+
+def _padded_adjacency(indptr: np.ndarray, idx: np.ndarray,
+                      machine: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """CSR neighbours plus each task's machine neighbour (-1 = none) as one
+    ``(n, width)`` index matrix padded with the sentinel ``n``, and the
+    per-task neighbour counts."""
+    n = len(indptr) - 1
+    dag = np.diff(indptr)
+    deg = dag + (machine >= 0)
+    cols = np.arange(max(int(deg.max(initial=0)), 1))
+    slot = np.where(cols < dag[:, None], indptr[:-1, None] + cols, len(idx))
+    mat = np.append(idx, n)[slot]            # slot len(idx): the sentinel
+    has = np.nonzero(machine >= 0)[0]
+    mat[has, dag[has]] = machine[has]
+    return mat, deg
+
+
+class FrozenOrder:
+    """``exact_schedule`` and ``heads_tails`` for one fixed ``assign`` and
+    ``proc_seq``, re-timed under any allocation ``mem``.
+
+    Built from a schedule of the solution, whose ``topo`` fixes the order
+    (and whose existence, the acyclicity).  The combined graph, DAG edges
+    plus each task's machine predecessor and successor, is kept as padded
+    index rows grouped by level, a task's level being the length of the
+    longest path that reaches it: every predecessor lies on an earlier level
+    and every successor on a later one.  The forward sweep takes a level's
+    starts as the max of its predecessors' finishes, the backward sweep its
+    tails as its duration plus the max of its successors' tails; a zero
+    sentinel pads the rows.  Longest-path values use only ``max``, which is
+    exact, and one add per task, so every array is bit-equal to what
+    ``exact_schedule`` and ``heads_tails`` give for the same ``mem``.
+    """
+
+    def __init__(self, inst: Instance, sol: Solution, sched: Schedule):
+        n = inst.n_tasks
+        self._inst, self._assign, self._topo = inst, sol.assign.copy(), sched.topo
+        mpred, msucc = sol.machine_pred_succ(n)
+        level = [0] * n
+        succ_indptr, succ_idx = inst.succ_indptr.tolist(), inst.succ_idx.tolist()
+        msucc_l = msucc.tolist()
+        for u in sched.topo.tolist():
+            nxt = level[u] + 1
+            for v in succ_idx[succ_indptr[u]:succ_indptr[u + 1]]:
+                if nxt > level[v]:
+                    level[v] = nxt
+            v = msucc_l[u]
+            if v >= 0 and nxt > level[v]:
+                level[v] = nxt
+        preds, n_pred = _padded_adjacency(inst.pred_indptr, inst.pred_idx, mpred)
+        succs, n_succ = _padded_adjacency(inst.succ_indptr, inst.succ_idx, msucc)
+        level = np.asarray(level, dtype=np.int64)
+        by_level = np.argsort(level, kind="stable")
+        edges = np.searchsorted(level[by_level], np.arange(level.max() + 2))
+        self._levels = []                    # (tasks, pred rows, succ rows)
+        for lo, hi in zip(edges[:-1], edges[1:]):
+            tasks = by_level[lo:hi]
+            self._levels.append((tasks, preds[tasks, :max(int(n_pred[tasks].max()), 1)],
+                                 succs[tasks, :max(int(n_succ[tasks].max()), 1)]))
+
+    def schedule(self, mem: np.ndarray) -> Schedule:
+        """``exact_schedule`` of the frozen order under ``mem``."""
+        n = self._inst.n_tasks
+        dur = durations(self._inst, self._assign, mem)
+        finish = np.zeros(n + 1)             # finish[n]: the sentinel
+        start = np.zeros(n)
+        for tasks, preds, _ in self._levels:
+            s = finish[preds].max(axis=1)
+            start[tasks] = s
+            finish[tasks] = s + dur[tasks]
+        finish = finish[:n]
+        return Schedule(start=start, finish=finish, makespan=float(finish.max()),
+                        topo=self._topo)
+
+    def heads_tails(self, sched: Schedule) -> tuple[np.ndarray, np.ndarray,
+                                                    np.ndarray, np.ndarray]:
+        """``heads_tails`` of the frozen order for ``sched``."""
+        n = self._inst.n_tasks
+        dur = sched.finish - sched.start
+        q = np.zeros(n + 1)                  # q[n]: the sentinel
+        for tasks, _, succs in reversed(self._levels):
+            q[tasks] = dur[tasks] + q[succs].max(axis=1)
+        q = q[:n]
+        r = sched.start
+        slack = sched.makespan - r - q
+        critical = slack <= _EPS * max(1.0, sched.makespan)
+        return r, q, slack, critical
 
 
 def data_lifetimes(inst: Instance, sched: Schedule) -> tuple[np.ndarray, np.ndarray]:
